@@ -1,7 +1,7 @@
 """smollm-360m [dense] — llama-arch small.
 
 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152
-[hf:HuggingFaceTB/SmolLM-135M; hf]
+[hf:HuggingFaceTB/SmolLM-360M; hf]
 """
 from .base import BlockSpec, ModelConfig
 

@@ -29,7 +29,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..configs.base import ModelConfig
@@ -286,7 +285,7 @@ def make_pipeline_loss(cfg: ModelConfig, pipe: PipelineConfig, mesh: Mesh,
     pspecs = jax.tree.map(leaf_spec, specs,
                           is_leaf=lambda x: isinstance(x, ParamSpec))
     bspec = P("data", None)
-    fn = shard_map(shard_body, mesh=mesh,
-                   in_specs=(pspecs, {"tokens": bspec, "labels": bspec}),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(shard_body, mesh=mesh,
+                       in_specs=(pspecs, {"tokens": bspec, "labels": bspec}),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)

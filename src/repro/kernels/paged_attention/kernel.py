@@ -1,7 +1,7 @@
 """Pallas TPU paged attention (decode) — TPU-native vLLM PagedAttention.
 
-Hardware adaptation (DESIGN.md §3): the CUDA kernel's warp-level gather has
-no TPU analogue; instead the page table rides in SMEM as a *scalar-prefetch*
+Hardware adaptation: the CUDA kernel's warp-level gather has no TPU
+analogue; instead the page table rides in SMEM as a *scalar-prefetch*
 operand (PrefetchScalarGridSpec) and the BlockSpec index_map dereferences it,
 so the pipeline's async copies stream exactly the pages each sequence needs
 HBM->VMEM.  Online-softmax accumulators live in VMEM scratch across the
@@ -23,7 +23,10 @@ instead of ``B * NP`` (see ``ops.streamed_pages_per_step``).
 
 Int8 KV: when per-page, per-kv-head scales are passed, K/V pages are int8
 and dequantized in-VMEM inside ``_compute`` (one (KH,)-scale row per page,
-riding the same clamped index map), halving decode HBM traffic again.
+riding the same clamped index map), halving decode HBM traffic again.  The
+(P, KH) scale arrays are viewed as (P, 1, KH) so each block is (1, 1, KH):
+its last two dims equal the array's, which the TPU's (8, 128) tiling rule
+accepts — a (1, KH) block over (P, KH) is refused by the compiler.
 """
 from __future__ import annotations
 
@@ -60,7 +63,7 @@ def _kernel(block_tables, lengths, q_ref, *refs, page: int, num_pages: int,
         q = q_ref[0].astype(jnp.float32) * scale          # (H, D)
         k = k_ref[0].astype(jnp.float32)                  # (page, KH, D)
         if quantized:
-            k = k * ks_ref[0][None, :, None]              # in-VMEM dequant
+            k = k * ks_ref[0][:, :, None]                 # in-VMEM dequant
         H, D = q.shape
         KH = k.shape[1]
         qg = q.reshape(KH, groups, D)
@@ -79,7 +82,7 @@ def _kernel(block_tables, lengths, q_ref, *refs, page: int, num_pages: int,
         l_scr[...] = l_scr[...] * corr + p.sum(axis=2)
         v = v_ref[0].astype(jnp.float32)                  # (page, KH, D)
         if quantized:
-            v = v * vs_ref[0][None, :, None]
+            v = v * vs_ref[0][:, :, None]
         pv = jax.lax.dot_general(
             p, v, (((2,), (0,)), ((0,), (1,))),
             preferred_element_type=jnp.float32)           # (KH, G, D)
@@ -123,14 +126,15 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         (1, page, KH, D), lambda b, ip, bt, ln: (page_id(b, ip, bt, ln),
                                                  0, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, KH), lambda b, ip, bt, ln: (page_id(b, ip, bt, ln), 0))
+        (1, 1, KH), lambda b, ip, bt, ln: (page_id(b, ip, bt, ln), 0, 0))
     q_spec = pl.BlockSpec((1, H, D), lambda b, ip, bt, ln: (b, 0, 0))
 
     kernel = functools.partial(_kernel, page=page, num_pages=NP,
                                groups=G, scale=scale, quantized=quantized)
     if quantized:
         in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec]
-        operands = (q, k_pages, k_scales, v_pages, v_scales)
+        operands = (q, k_pages, k_scales.reshape(P, 1, KH), v_pages,
+                    v_scales.reshape(P, 1, KH))
     else:
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (q, k_pages, v_pages)

@@ -28,6 +28,7 @@ from repro.core.cluster import (COORDINATOR, DEVICE_PROFILES, ClusterSpec,
 from repro.dist.pipeline import (PipelineConfig, make_pipeline_loss,
                                  pipeline_param_specs,
                                  stage_units_from_placement)
+from repro.launch.mesh import make_mesh
 from repro.models.common import abstract_shapes
 from repro.roofline.hlo import collective_totals
 
@@ -104,7 +105,7 @@ def main() -> None:
               f"(per-data-shard batch is {args.batch // data_dim})")
     pipe = PipelineConfig(num_stages=args.stages, stage_units=tuple(units),
                           num_microbatches=microbatches)
-    mesh = jax.make_mesh((args.stages, data_dim), ("stage", "data"))
+    mesh = make_mesh((args.stages, data_dim), ("stage", "data"))
     specs = pipeline_param_specs(cfg, pipe)
     params_abs = abstract_shapes(specs, cfg.param_dtype)
     batch_abs = {

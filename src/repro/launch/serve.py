@@ -42,6 +42,8 @@ from repro.configs import get_config, get_smoke_config
 from repro.core import (MILPOptions, ModelProfile, make_serving_cluster,
                         plan)
 from repro.dist.sharding import SERVE_RULES, tree_shardings
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import abstract_params
 from repro.models import decode_step, init, init_caches, prefill
 from repro.models import model as M
@@ -83,19 +85,26 @@ def run_paged(cfg, args) -> None:
     print("sampled ids:", [r.output for r in reqs[:2]])
 
 
+def cluster_plan(cfg, devs, *, stages: int = 0, kv_dtype=None,
+                 page_size: int = 16):
+    """MILP placement of ``cfg`` over a full-mesh cluster of ``devs``
+    (device-profile names), VRAM-derated to force >= ``stages`` stages."""
+    profile = ModelProfile.from_dims(
+        cfg.name, cfg.num_layers, cfg.d_model, max(cfg.d_ff, 1),
+        cfg.vocab_size, cfg.num_kv_heads, cfg.resolved_head_dim,
+        kv_dtype=kv_dtype, kv_page_size=page_size)
+    cluster = make_serving_cluster(profile, devs=devs, force_stages=stages)
+    return plan(cluster, profile, MILPOptions(time_limit_s=10.0,
+                                              lns_rounds=0, fgls_rounds=20))
+
+
 def run_cluster(cfg, args) -> None:
     """Multi-node serving: MILP placement over a (VRAM-derated) cluster, one
     stage engine per node, requests walking IWRR pipelines through the
     ClusterRuntime."""
     kv_dtype = args.kv_dtype if args.kv_dtype != "param" else None
-    profile = ModelProfile.from_dims(
-        cfg.name, cfg.num_layers, cfg.d_model, max(cfg.d_ff, 1),
-        cfg.vocab_size, cfg.num_kv_heads, cfg.resolved_head_dim,
-        kv_dtype=args.kv_dtype, kv_page_size=args.page_size)
-    cluster = make_serving_cluster(profile, devs=args.cluster.split(","),
-                                   force_stages=args.stages)
-    p = plan(cluster, profile, MILPOptions(time_limit_s=10.0, lns_rounds=0,
-                                           fgls_rounds=20))
+    p = cluster_plan(cfg, args.cluster.split(","), stages=args.stages,
+                     kv_dtype=kv_dtype, page_size=args.page_size)
     for node, rng_ in sorted(p.placement.assignment.items()):
         print(f"  {node}: layers [{rng_.start}, {rng_.end})")
     params = init(cfg, jax.random.key(0))
@@ -295,6 +304,7 @@ def main() -> None:
                          "models look infinitely fast to the paper-profile "
                          "table otherwise; 0 = use real device profiles)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.cluster:
@@ -307,7 +317,7 @@ def main() -> None:
         else (jax.device_count(), 1)
     axes = ("data", "model")[:len(dims)] if len(dims) == 2 \
         else ("pod", "data", "model")
-    mesh = jax.make_mesh(dims, axes)
+    mesh = make_mesh(dims, axes)
     print(f"mesh {dict(zip(axes, dims))}; serving {cfg.name}")
 
     params_abs, params_axes = abstract_params(cfg)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import optimizer_for
 from repro.models import init
 from repro.training import (AsyncCheckpointer, DataConfig, TrainConfig,
@@ -46,7 +47,7 @@ def main() -> None:
         dims = (jax.device_count(), 1)
     axes = ("data", "model")[:len(dims)] if len(dims) == 2 \
         else ("pod", "data", "model")
-    mesh = jax.make_mesh(dims, axes)
+    mesh = make_mesh(dims, axes)
     print(f"mesh {dict(zip(axes, dims))}; model {cfg.name} "
           f"({cfg.param_count() / 1e6:.1f}M params)")
 

@@ -63,6 +63,7 @@ from ..serving.stage_engine import DecodeItem, PagedStageEngine, StageEngine
 from ..serving.transport import (FrameError, StagedRef, WorkerChannel,
                                  WorkerDied, decode_payload, encode_payload,
                                  recv_frame, send_frame)
+from .compile_cache import use_compile_cache
 
 # staged payloads whose pass got cancelled (epoch bump) are never resolved;
 # cap the stash so they can't accumulate across a long-lived worker
@@ -174,8 +175,7 @@ class StageWorker:
             self.engine = PagedStageEngine(
                 cfg, spec["params"], layers, ec,
                 num_pages=spec["num_pages"], page_size=spec["page_size"],
-                kv_dtype=spec.get("kv_dtype"),
-                interpret=spec["interpret"], rng_seed=spec["rng_seed"])
+                kv_dtype=spec.get("kv_dtype"), rng_seed=spec["rng_seed"])
         else:
             self.engine = StageEngine(cfg, spec["params"], layers, ec,
                                       rng_seed=spec["rng_seed"])
@@ -346,6 +346,7 @@ def main() -> None:
                          "dead coordinator closes the socket, which exits "
                          "the worker)")
     args = ap.parse_args()
+    use_compile_cache()
     host, _, port = args.connect.rpartition(":")
     run_worker(host or "127.0.0.1", int(port), timeout_s=args.timeout_s)
 

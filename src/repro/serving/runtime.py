@@ -102,6 +102,7 @@ import time
 from collections import defaultdict, deque
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ..configs.base import ModelConfig
@@ -292,7 +293,7 @@ class ClusterRuntime:
                  kv_dtype: Optional[str] = None,
                  pool_pages: Optional[Mapping[str, int]] = None,
                  transport: Optional[Transport] = None,
-                 interpret: Optional[bool] = None, rng_seed: int = 0,
+                 rng_seed: int = 0,
                  max_inflight: int = 1,
                  engine_factory: Optional[Callable[["ClusterRuntime", str,
                                                     LayerRange], Any]] = None,
@@ -310,7 +311,6 @@ class ClusterRuntime:
         self.page_size = page_size
         self.kv_dtype = kv_dtype
         self.pool_pages = dict(pool_pages or {})
-        self.interpret = interpret
         self.rng_seed = rng_seed
         self.stall_timeout_s = stall_timeout_s
         self._engine_factory = engine_factory
@@ -382,7 +382,8 @@ class ClusterRuntime:
         self.workers: Dict[str, Any] = {}   # node -> worker process handle
         self.engines: Dict[str, Any] = {}
         for node, rng in sorted(self.placement.assignment.items()):
-            self.engines[node] = self._make_engine(node, rng)
+            self.engines[node] = self._make_engine(
+                node, rng, self.placement.assignment)
         self._sync_kv(capacities=True)
 
         self.queue: deque = deque()      # _Job awaiting admission
@@ -442,19 +443,26 @@ class ClusterRuntime:
             pages = max(pages, 1 + blocks * n_paged)
         return {"paged": True, "num_pages": pages, "kv_dtype": self.kv_dtype}
 
-    def _make_engine(self, node: str, rng: LayerRange):
-        if self._engine_factory is not None:
-            return self._engine_factory(self, node, rng)
-        spec = self._engine_spec(node, rng)
-        if not spec["paged"]:
-            return StageEngine(self.cfg, self.params, rng, self.ec,
-                               rng_seed=self.rng_seed)
-        return PagedStageEngine(self.cfg, self.params, rng, self.ec,
-                                num_pages=spec["num_pages"],
-                                page_size=self.page_size,
-                                kv_dtype=spec["kv_dtype"],
-                                interpret=self.interpret,
-                                rng_seed=self.rng_seed)
+    def _make_engine(self, node: str, rng: LayerRange,
+                     assignment: Mapping[str, LayerRange]):
+        """Build ``node``'s engine on its own device: node *i* in sorted
+        placement order gets ``jax.devices()[i % n]``, so one process drives
+        every chip of its host with one stage per chip (on one device this
+        is the default device, as before)."""
+        devs = jax.devices()
+        dev = devs[sorted(assignment).index(node) % len(devs)]
+        with jax.default_device(dev):
+            if self._engine_factory is not None:
+                return self._engine_factory(self, node, rng)
+            spec = self._engine_spec(node, rng)
+            if not spec["paged"]:
+                return StageEngine(self.cfg, self.params, rng, self.ec,
+                                   rng_seed=self.rng_seed)
+            return PagedStageEngine(self.cfg, self.params, rng, self.ec,
+                                    num_pages=spec["num_pages"],
+                                    page_size=self.page_size,
+                                    kv_dtype=spec["kv_dtype"],
+                                    rng_seed=self.rng_seed)
 
     # -- role schedulers (disaggregated prefill/decode) -----------------------
     def _build_role_schedulers(self, plan) -> None:
@@ -1574,7 +1582,7 @@ class ClusterRuntime:
             for job in list(self.jobs.values()):
                 if node in job.slots:
                     self._requeue(job, clear_pipe=True)
-            self.engines[node] = self._make_engine(node, rng)
+            self.engines[node] = self._make_engine(node, rng, new_assign)
         # queued jobs (e.g. preempted ones holding their old pipeline) whose
         # cached pipeline crosses a rebuilt node would execute stale layer
         # ranges — force them to reschedule
@@ -1656,7 +1664,19 @@ class ClusterRuntime:
         ``apply_plan`` re-inits surviving workers whose slice moved over
         their existing channels and respawns processes for dead nodes that
         re-enter the placement.  Call ``shutdown()`` when done.
+
+        Local workers are refused when this process runs on an accelerator:
+        it already holds the chip, so a child could not open it.  There,
+        build the in-process runtime (one stage engine per device) or
+        start workers yourself and pass ``connect``.
         """
+        if connect is None and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"spawn_workers: this process holds the "
+                f"{jax.default_backend()} device, so local worker processes "
+                "could not open it.  Use the in-process ClusterRuntime, "
+                "which puts each stage engine on its own device, or start "
+                "workers yourself and pass connect='host:port'.")
         nodes = sorted(plan.placement.assignment)
         channels: Dict[str, WorkerChannel] = {}
         procs: Dict[str, Any] = {}
@@ -1740,7 +1760,6 @@ class ClusterRuntime:
                     pass
 
         def factory(rt: "ClusterRuntime", node: str, rng: LayerRange):
-            import jax
             # converted per init/respawn and then dropped — holding a
             # permanent numpy copy would double the coordinator's weight
             # footprint for the runtime's whole life
@@ -1762,7 +1781,7 @@ class ClusterRuntime:
                 "layers": (rng.start, rng.end), "params": params_np,
                 "paged": spec["paged"], "num_pages": spec["num_pages"],
                 "page_size": rt.page_size, "kv_dtype": spec["kv_dtype"],
-                "interpret": rt.interpret, "rng_seed": rt.rng_seed})
+                "rng_seed": rt.rng_seed})
             if direct_links:
                 _wire_peers()
             return RemoteStageEngine(ch, node, rng_seed=rt.rng_seed)

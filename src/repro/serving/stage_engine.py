@@ -25,6 +25,12 @@ and scratch writes land in cache rows (or page 0) nothing ever reads.
 The paged engine's ``PagePool`` is sized from the node's own VRAM with the
 page cost of its *local* paged-layer count, so memory heterogeneity shows up
 as genuinely different pool depths per node.
+
+Device: an engine computes on the default device in effect when it is built
+(``ClusterRuntime`` builds node *i* under ``jax.default_device`` of device
+*i*), and commits its params, caches and pool there; host inputs — tokens,
+block tables, activations from the previous stage — are put on that device
+before each step.
 """
 from __future__ import annotations
 
@@ -32,7 +38,6 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
@@ -100,12 +105,21 @@ class _StageEngineBase:
         self.cfg = cfg
         self.layers = layers
         self.ec = engine_cfg
-        self.sparams = stage_params(cfg, params, layers)
+        dev = jax.config.jax_default_device
+        self.device = dev if isinstance(dev, jax.Device) else jax.devices()[0]
+        # slice where the full params live, then move only the slice
+        with jax.default_device(_home_device(params) or self.device):
+            sparams = stage_params(cfg, params, layers)
+        self.sparams = self._put(sparams)
         self.is_first = layers.start == 0
         self.is_last = layers.end == cfg.num_layers
         self.slots: List[Optional[int]] = [None] * engine_cfg.max_batch
         self._scratch = engine_cfg.max_batch   # padding row, never allocated
         self._rng = np.random.RandomState(rng_seed)
+
+    def _put(self, tree):
+        """Commit ``tree`` (host or device arrays) to this engine's device."""
+        return jax.device_put(tree, self.device)
 
     # -- slots ----------------------------------------------------------
     def alloc_slot(self, request_id: int) -> Optional[int]:
@@ -171,8 +185,7 @@ class _StageEngineBase:
             entry[i] = it.entry
             if it.h is not None:
                 h_in[i] = it.h
-        return (jnp.asarray(idx), jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(entry), jnp.asarray(h_in))
+        return self._put((idx, tok, pos, entry, h_in))
 
     # -- decode orchestration ---------------------------------------------
     def _decode_step(self, items: List[DecodeItem]):
@@ -237,6 +250,14 @@ class _StageEngineBase:
         return outs
 
 
+def _home_device(params):
+    """The one device ``params`` live on, or None for host arrays."""
+    leaf = jax.tree.leaves(params)[0]
+    if isinstance(leaf, jax.Array) and len(leaf.devices()) == 1:
+        return next(iter(leaf.devices()))
+    return None
+
+
 def _splice(full, one, slot: int):
     """Copy a batch-1 cache leaf into row ``slot`` of the engine leaf."""
     return full.at[slot].set(one[0])
@@ -249,8 +270,9 @@ class StageEngine(_StageEngineBase):
                  engine_cfg: EngineConfig, rng_seed: int = 0):
         super().__init__(cfg, params, layers, engine_cfg, rng_seed)
         ec = engine_cfg
-        self.caches = stage_cache_init(cfg, layers, ec.max_batch + 1,
-                                       ec.max_len)
+        self.caches = self._put(stage_cache_init(cfg, layers,
+                                                 ec.max_batch + 1,
+                                                 ec.max_len))
         self._prefill = jax.jit(
             lambda sp, x, entry: stage_prefill(cfg, sp, layers, x, entry,
                                                max_len=ec.max_len),
@@ -273,10 +295,10 @@ class StageEngine(_StageEngineBase):
         activations, or (V,) last-token logits at the final stage."""
         if entry == 0:
             S = len(x)
-            xin = jnp.asarray(np.asarray(x, np.int32))[None, :]
+            xin = self._put(np.asarray(x, np.int32)[None, :])
         else:
             S = x.shape[1]
-            xin = jnp.asarray(x)
+            xin = self._put(x)
         out, caches1 = self._prefill(self.sparams, xin, entry)
         self.caches = jax.tree.map(
             lambda full, one: _splice(full, one, slot), self.caches, caches1)
@@ -333,7 +355,7 @@ class StageEngine(_StageEngineBase):
                 new.append(c)
             else:
                 new.append(jax.tree.map(
-                    lambda full, a: full.at[slot].set(jnp.asarray(a)),
+                    lambda full, a: full.at[slot].set(self._put(a)),
                     c, one))
         self.caches = new
         self._active_tokens[slot] = tokens
@@ -367,8 +389,11 @@ class PagedStageEngine(_StageEngineBase):
         self.pool = PagePool(cfg, num_pages=num_pages, page_size=page_size,
                              max_batch=ec.max_batch + 1, max_seq_len=ec.max_len,
                              paged_layers=self.n_paged, kv_dtype=kv_dtype)
-        self.caches = stage_cache_init_paged(cfg, layers, ec.max_batch + 1,
-                                             ec.max_len)
+        self.caches = self._put(stage_cache_init_paged(
+            cfg, layers, ec.max_batch + 1, ec.max_len))
+        pool = self.pool
+        pool.k, pool.v, pool.k_scales, pool.v_scales = self._put(
+            (pool.k, pool.v, pool.k_scales, pool.v_scales))
         on_cpu = jax.default_backend() == "cpu"
         if self._chunked:
             def _chunk(sp, x, entry, start, kp, vp, ks, vs, tb, *,
@@ -422,17 +447,18 @@ class PagedStageEngine(_StageEngineBase):
         tokens or (1, C, d) activations.  Returns chunk activations
         (1, C, d), or last-token logits (V,) at the final stage."""
         if entry == 0:
-            xin = jnp.asarray(np.asarray(x, np.int32))[None, :]
+            xin = self._put(np.asarray(x, np.int32)[None, :])
         else:
-            xin = jnp.asarray(x)
+            xin = self._put(x)
         C = xin.shape[1]
-        tb = jnp.asarray(self.pool.table[:, slot:slot + 1])
+        tb = self._put(self.pool.table[:, slot:slot + 1])
         n_act = _active_blocks_bucket(start + C, self.pool.page,
                                       self.pool.blocks_per_seq)
         pool = self.pool
         out, pool.k, pool.v, pool.k_scales, pool.v_scales = \
             self._prefill_chunk(
-                self.sparams, xin, entry, jnp.asarray([start], jnp.int32),
+                self.sparams, xin, entry,
+                self._put(np.asarray([start], np.int32)),
                 pool.k, pool.v, pool.k_scales, pool.v_scales, tb,
                 n_act=n_act)
         return np.asarray(out)[0] if self.is_last else np.asarray(out)
@@ -445,10 +471,10 @@ class PagedStageEngine(_StageEngineBase):
             raise RuntimeError("all-paged slice: drive prefill_chunk instead")
         if entry == 0:
             S = len(x)
-            xin = jnp.asarray(np.asarray(x, np.int32))[None, :]
+            xin = self._put(np.asarray(x, np.int32)[None, :])
         else:
             S = x.shape[1]
-            xin = jnp.asarray(x)
+            xin = self._put(x)
         out, caches1 = self._prefill_one(self.sparams, xin, entry)
         pool = self.pool
         caches1, pool.k, pool.v, pool.k_scales, pool.v_scales = \
@@ -507,20 +533,20 @@ class PagedStageEngine(_StageEngineBase):
             if p is None:
                 new.append(c)
             elif paged:
-                pids = jnp.asarray(pool.table[li, slot, :nb])
+                pids = self._put(pool.table[li, slot, :nb])
                 pool.k = pool.k.at[pids].set(
-                    jnp.asarray(p["k"]).astype(pool.k.dtype))
+                    self._put(p["k"]).astype(pool.k.dtype))
                 pool.v = pool.v.at[pids].set(
-                    jnp.asarray(p["v"]).astype(pool.v.dtype))
+                    self._put(p["v"]).astype(pool.v.dtype))
                 if pool.quantized:
                     pool.k_scales = pool.k_scales.at[pids].set(
-                        jnp.asarray(p["ks"]))
+                        self._put(p["ks"]))
                     pool.v_scales = pool.v_scales.at[pids].set(
-                        jnp.asarray(p["vs"]))
+                        self._put(p["vs"]))
                 new.append(c)
             else:
                 new.append(jax.tree.map(
-                    lambda full, a: full.at[slot].set(jnp.asarray(a)),
+                    lambda full, a: full.at[slot].set(self._put(a)),
                     c, p))
             if paged:
                 li += 1
@@ -529,7 +555,7 @@ class PagedStageEngine(_StageEngineBase):
     # -- decode ----------------------------------------------------------
     def _decode_step(self, items: List[DecodeItem]):
         idx, tok, pos, entry, h_in = self._assemble(items)
-        tables = jnp.asarray(self.pool.table)
+        tables = self._put(self.pool.table)
         pool = self.pool
         (h, logits, self.caches, pool.k, pool.v,
          pool.k_scales, pool.v_scales) = self._decode(
@@ -578,12 +604,12 @@ class PagedStageEngine(_StageEngineBase):
             snap = snaps.get(tokens)
             if snap is not None:
                 for pid, (k, v, ks, vs) in snap.items():
-                    pool.k = pool.k.at[pid].set(jnp.asarray(k))
-                    pool.v = pool.v.at[pid].set(jnp.asarray(v))
+                    pool.k = pool.k.at[pid].set(self._put(k))
+                    pool.v = pool.v.at[pid].set(self._put(v))
                     pool.k_scales = pool.k_scales.at[pid].set(
-                        jnp.asarray(ks))
+                        self._put(ks))
                     pool.v_scales = pool.v_scales.at[pid].set(
-                        jnp.asarray(vs))
+                        self._put(vs))
         pool.truncate(slot, tokens)
 
 

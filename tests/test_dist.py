@@ -23,6 +23,7 @@ from repro.dist import (SERVE_RULES, TRAIN_RULES, PipelineConfig,
                         compressed_psum, make_pipeline_loss,
                         pipeline_param_specs, sharding_for,
                         stage_units_from_placement)
+from repro.launch.mesh import make_mesh
 from repro.models import forward, init, loss_fn
 from repro.models.common import init_params, logical_axes
 
@@ -34,7 +35,7 @@ def need_devices(n):
 
 def test_sharding_rules_basic():
     need_devices(8)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     s = sharding_for((64, 16, 8), ("embed", "heads", "head_dim"),
                      TRAIN_RULES, mesh)
     assert s.spec == P("data", "model")
@@ -45,7 +46,7 @@ def test_sharding_rules_basic():
 
 def test_sharding_no_duplicate_axes():
     need_devices(8)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     s = sharding_for((8, 64, 32), ("experts", "embed", "ff"),
                      TRAIN_RULES, mesh)
     flat = []
@@ -59,13 +60,12 @@ def test_sharding_no_duplicate_axes():
 
 def test_compressed_psum_accuracy():
     need_devices(8)
-    from jax.experimental.shard_map import shard_map
     import functools
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = make_mesh((8,), ("pod",))
     x = jax.random.normal(jax.random.key(0), (8, 128)) * 0.01
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("pod"),
-                       out_specs=P("pod"), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                       out_specs=P("pod"), check_vma=False)
     def f(x):
         return compressed_psum(x[0], "pod")[None]
 
@@ -98,7 +98,7 @@ def test_pipeline_loss_matches_reference():
     single-program loss on identical params."""
     need_devices(8)
     cfg = _tiny_cfg()
-    mesh = jax.make_mesh((2, 4), ("stage", "data"))
+    mesh = make_mesh((2, 4), ("stage", "data"))
     pipe = PipelineConfig(num_stages=2, stage_units=(3, 1),
                           num_microbatches=4)
 
@@ -129,7 +129,7 @@ def test_pipeline_loss_matches_reference():
 def test_pipeline_grad_runs():
     need_devices(8)
     cfg = _tiny_cfg()
-    mesh = jax.make_mesh((2, 4), ("stage", "data"))
+    mesh = make_mesh((2, 4), ("stage", "data"))
     pipe = PipelineConfig(num_stages=2, stage_units=(2, 2),
                           num_microbatches=2)
     specs = pipeline_param_specs(cfg, pipe)

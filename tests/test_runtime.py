@@ -435,3 +435,68 @@ def test_stage_engine_holds_only_its_slice(gqa_model):
     assert "embed" not in eng.sparams       # neither first nor last stage
     assert "final_norm" not in eng.sparams
     assert eng.pool.num_layers == 2         # pool priced at *local* layers
+
+
+# --- one process, one device per stage ---------------------------------------
+
+def test_four_stages_on_four_devices(gqa_model, reference):
+    """Rehearsal of the four-chip path on host devices: a 4-stage placement
+    in one process puts node i's params, caches and pool on device i,
+    serves byte-identically to the single engine, and drains every pool."""
+    import jax
+    if jax.device_count() < 4:
+        pytest.skip(f"needs 4 host devices, have {jax.device_count()}")
+    cfg, params = gqa_model
+    prompts, ref = reference
+    p = make_plan(cfg, {f"n{i}": (i, i + 1) for i in range(4)})
+    rt = assert_serves_like_reference(cfg, params, p, prompts, ref,
+                                      paged=True)
+    nodes = sorted(rt.engines)
+    assert [rt.engines[n].device for n in nodes] == jax.devices()[:4]
+    for n in nodes:
+        eng = rt.engines[n]
+        arrays = jax.tree.leaves((eng.sparams, eng.caches, eng.pool.k,
+                                  eng.pool.v))
+        assert {d for a in arrays for d in a.devices()} == {eng.device}, n
+
+
+def test_spawn_workers_refused_on_accelerator(gqa_model, monkeypatch):
+    """A parent on an accelerator holds the chip, so local worker children
+    could not open it: spawn_workers refuses before starting any."""
+    import jax
+    import subprocess
+    cfg, params = gqa_model
+    p = make_plan(cfg, {"n0": (0, 2), "n1": (2, 4)})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen", None)   # must never be reached
+    with pytest.raises(RuntimeError, match="holds the tpu device"):
+        ClusterRuntime.spawn_workers(cfg, params, p, EC)
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"], ids=["unset", "set"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """Entry points keep the compile cache where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed path in the checkout; importing sets nothing."""
+    import importlib
+
+    import jax
+
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    importlib.reload(compile_cache)
+    for mod in ("repro.launch.serve", "repro.launch.worker"):
+        importlib.import_module(mod)
+    assert jax.config.jax_compilation_cache_dir == before
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        compile_cache.use_compile_cache()
+        want = before if env_dir else str(compile_cache.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compile_cache.CACHE_DIR.parent.joinpath(
+            "src", "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

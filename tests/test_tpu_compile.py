@@ -1,0 +1,142 @@
+"""Main-path programs compiled for a described TPU v5e chip.
+
+Nothing runs: the TPU compiler, which is installed even where no chip is
+attached, compiles the decode kernel and the stage engine's steps at
+SmolLM-360M's published widths for one chip of a described ``v5e:2x2``
+topology.  This catches what interpret mode cannot — block shapes the
+TPU's tiling refuses, programs that do not fit the chip — at no chip time.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.placement import LayerRange
+from repro.kernels.paged_attention import paged_attention
+from repro.models import init
+from repro.models.stage import (stage_decode_paged, stage_params,
+                                stage_prefill_chunk_paged)
+
+CFG = get_config("smollm_360m")
+PAGE = 16
+MAX_BATCH = 8            # the served engine adds one scratch row
+MAX_LEN = 2048
+NP = MAX_LEN // PAGE
+# the full rectangle of a one-node engine: every slot holds max_len tokens
+NUM_PAGES = 1 + NP * CFG.num_layers * MAX_BATCH
+HBM_BYTES = 16e9         # one TPU v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A program compiled for a described chip is written to the persistent
+    cache but cannot be read back without one: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _stage_params_shapes(sharding, layers):
+    full = jax.eval_shape(lambda: init(CFG, jax.random.key(0)))
+    sliced = jax.eval_shape(lambda p: stage_params(CFG, p, layers), full)
+    return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), sliced)
+
+
+def _pool_shapes(sharding, quantized):
+    kh, d = CFG.num_kv_heads, CFG.resolved_head_dim
+    dt = jnp.int8 if quantized else jnp.bfloat16
+    pages = _spec(sharding, (NUM_PAGES, PAGE, kh, d), dt)
+    scales = _spec(sharding, (NUM_PAGES, kh), jnp.float32) if quantized \
+        else None
+    return pages, scales
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_compiles(one_chip, quantized):
+    B, H, KH, D = MAX_BATCH, CFG.num_heads, CFG.num_kv_heads, \
+        CFG.resolved_head_dim
+    pages, scales = _pool_shapes(one_chip, quantized)
+    q = _spec(one_chip, (B, H, D), jnp.bfloat16)
+    tables = _spec(one_chip, (B, NP), jnp.int32)
+    lengths = _spec(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(
+        lambda q, k, v, t, n, ks, vs: paged_attention(
+            q, k, v, t, n, k_scales=ks, v_scales=vs)
+    ).lower(q, pages, pages, tables, lengths, scales, scales).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_stage_decode_step_compiles(one_chip, quantized):
+    """The one-node engine's decode step: all 32 layers, the Pallas kernel
+    compiled (not interpreted), pool donated as the engine donates it."""
+    layers = LayerRange(0, CFG.num_layers)
+    sp = _stage_params_shapes(one_chip, layers)
+    pages, scales = _pool_shapes(one_chip, quantized)
+    B = MAX_BATCH + 1
+    row = lambda dt: _spec(one_chip, (B,), dt)
+    h_in = _spec(one_chip, (B, 1, CFG.d_model), jnp.float32)
+    tables = _spec(one_chip, (CFG.num_layers, B, NP), jnp.int32)
+
+    def step(sp, tok, h, entry, pos, kp, vp, ks, vs, tb):
+        caches = [{}] * CFG.num_layers
+        return stage_decode_paged(CFG, sp, layers, tok, h, entry, caches,
+                                  pos, kp, vp, tb, k_scales=ks, v_scales=vs,
+                                  interpret=False)
+
+    compiled = jax.jit(step, donate_argnums=(5, 6, 7, 8)).lower(
+        sp, row(jnp.int32), h_in, row(jnp.int32), row(jnp.int32), pages,
+        pages, scales, scales, tables).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_stage_prefill_chunk_compiles(one_chip):
+    """A 256-token chunked-prefill step over all 32 layers, at the gather
+    cap of a 512-token prompt, fits one chip."""
+    layers = LayerRange(0, CFG.num_layers)
+    sp = _stage_params_shapes(one_chip, layers)
+    pages, _ = _pool_shapes(one_chip, False)
+    C = 256
+    compiled = jax.jit(
+        lambda sp, x, start, kp, vp, tb: stage_prefill_chunk_paged(
+            CFG, sp, layers, x, 0, start, kp, vp, tb, active_blocks=32),
+        donate_argnums=(3, 4),
+    ).lower(sp, _spec(one_chip, (1, C), jnp.int32),
+            _spec(one_chip, (1,), jnp.int32), pages, pages,
+            _spec(one_chip, (CFG.num_layers, 1, NP), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
