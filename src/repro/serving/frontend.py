@@ -9,6 +9,7 @@ so this module turns the offline trace-replay runtime into a server:
   GET  /v1/models             the single served model
   GET  /healthz               liveness + runtime ``_state()`` diagnostics
                               + the server-side latency summary so far
+                              + the tracer's counters and span totals
 
 Streaming semantics: one SSE ``data:`` chunk per token the coordinator
 *confirms* — the runtime's ``on_token`` callback fires in strict output
@@ -49,6 +50,7 @@ import numpy as np
 
 from .engine import Request
 from .runtime import ClusterRuntime
+from .trace import TRACER
 
 # ---------------------------------------------------------------------------
 # tokenizer-less text codec
@@ -350,6 +352,7 @@ class _Handler(BaseHTTPRequestHandler):
                 "error": repr(fe.loop_error) if fe.loop_error else None,
                 "state": state,
                 "metrics": fe.summary(),
+                "trace": TRACER.summary(),
             })
         else:
             self._error(404, f"no route {self.path}")
@@ -396,7 +399,8 @@ class _Handler(BaseHTTPRequestHandler):
         ch: "_queue.Queue" = _queue.Queue()
         try:
             fe.rt.submit(req,
-                         on_token=lambda t: ch.put(("tok", t)),
+                         on_token=lambda t: ch.put(
+                             ("tok", (t, TRACER.clock()))),
                          on_done=lambda r: ch.put(("done", r)))
         except ValueError as e:
             self._error(400, str(e))
@@ -443,9 +447,12 @@ class _Handler(BaseHTTPRequestHandler):
             while True:
                 kind, val = ch.get(timeout=fe.request_timeout_s)
                 if kind == "tok":
+                    tok, t_confirmed = val
                     self.wfile.write(self._chunk(req, chat, idx=idx,
-                                                 tok=val, finish=None))
+                                                 tok=tok, finish=None))
                     self.wfile.flush()
+                    TRACER.record("helix.frontend.write", t_confirmed,
+                                  TRACER.clock(), request=req.request_id)
                     idx += 1
                 else:
                     fe.record(val)   # before the socket: stats never

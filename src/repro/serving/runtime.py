@@ -114,6 +114,7 @@ from .engine import EngineConfig, Request
 from .kv_pool import full_rectangle_pages, pages_for_vram
 from .stage_engine import (DecodeItem, PagedStageEngine, StageEngine,
                            make_stage_engine)
+from .trace import TRACER
 from .transport import (RemoteStageEngine, SocketTransport, WorkerChannel,
                         WorkerDied)
 
@@ -268,6 +269,8 @@ class _Job:
                                      # per-stage next expected chunk offset
     hop_stash: Dict[int, Dict[int, Any]] = dataclasses.field(
         default_factory=dict)        # reordered chunks awaiting predecessors
+    t_queued: float = 0.0            # tracer clock: when it last joined
+                                     # the admission queue
 
     @property
     def resumed(self) -> bool:
@@ -586,9 +589,12 @@ class ClusterRuntime:
         req.submitted_s = self.clock()
         if on_token is not None or on_done is not None:
             self._listeners[req.request_id] = (on_token, on_done)
+        job = _Job(req, t_queued=TRACER.clock())
+        TRACER.record("helix.request.submit", job.t_queued, job.t_queued,
+                      request=req.request_id)
         with self._ingest_lock:
             self._ingest_jobs += 1
-        self._ingest.put(_Job(req))
+        self._ingest.put(job)
         self._mailbox.put(lambda: None)   # wake an idle serve loop
 
     def cancel(self, request_id: int) -> None:
@@ -750,10 +756,12 @@ class ClusterRuntime:
         work, so a deadlocked run still fails fast with diagnostics
         instead of hanging CI."""
         try:
-            fn = self._mailbox.get(timeout=timeout_s)
+            with TRACER.span("helix.idle"):
+                fn = self._mailbox.get(timeout=timeout_s)
         except _queue.Empty:
             return False
-        fn()
+        with TRACER.span("helix.deliver"):
+            fn()
         return True
 
     def _state(self) -> str:
@@ -779,31 +787,42 @@ class ClusterRuntime:
         """One runtime iteration: admit, drain deliveries due now, then one
         batched decode per node with resident stage-work.  Returns whether
         anything progressed."""
-        if self.realtime:
-            self._now = max(self._now, time.monotonic() - self._t0)
-        self._drain_ingest()
-        progressed = self._admit()
+        with TRACER.span("helix.step"):
+            if self.realtime:
+                self._now = max(self._now, time.monotonic() - self._t0)
+            with TRACER.span("helix.admit"):
+                self._drain_ingest()
+                progressed = self._admit()
+            with TRACER.span("helix.deliver"):
+                progressed = self._deliver() or progressed
+            for node in [n for n, v in self._ready.items() if v]:
+                work = self._ready.pop(node)
+                work = [w for w in work if w["job"].epoch == w["epoch"]]
+                if work:
+                    self._decode_node(node, work)
+                    progressed = True
+            with TRACER.span("helix.sync_kv"):
+                self._sync_kv()
+            return progressed
+
+    def _deliver(self) -> bool:
+        """Run the deliveries due now: virtual-clock events, then the
+        wall-clock mailbox.  Returns whether any ran."""
+        ran = False
         if self._events:
             self._now = max(self._now, self._events[0][0])
             while self._events and self._events[0][0] <= self._now + 1e-12:
                 _, _, fn = heapq.heappop(self._events)
                 fn()
-                progressed = True
+                ran = True
         while True:                  # wall-clock deliveries (socket runs)
             try:
                 fn = self._mailbox.get_nowait()
             except _queue.Empty:
                 break
             fn()
-            progressed = True
-        for node in [n for n, v in self._ready.items() if v]:
-            work = self._ready.pop(node)
-            work = [w for w in work if w["job"].epoch == w["epoch"]]
-            if work:
-                self._decode_node(node, work)
-                progressed = True
-        self._sync_kv()
-        return progressed
+            ran = True
+        return ran
 
     # -- KV feedback --------------------------------------------------------
     def _sync_kv(self, capacities: bool = False) -> None:
@@ -887,6 +906,9 @@ class ClusterRuntime:
                     self.engines[node].release(slot)
                 break                   # FIFO: wait for running work to free
             self.queue.popleft()
+            TRACER.record("helix.request.queued", job.t_queued,
+                          TRACER.clock(), request=job.req.request_id,
+                          resumed=int(job.resumed))
             job.slots = dict(taken)
             job.pos = S
             job.kv_pending = {(si, dst)
@@ -1440,49 +1462,58 @@ class ClusterRuntime:
                     nxt = (None if w["si"] == len(pipe.stages) - 1
                            else pipe.stages[w["si"] + 1].node)
                     fwds.append(self._fwd_spec(eng, nxt))
-            t_pass = time.monotonic()
-            if fwds and any(f is not None for f in fwds):
-                outs = eng.decode_stage(items, fwds=fwds)
-            else:
-                outs = eng.decode_stage(items)
+            with TRACER.span("helix.decode", node=node,
+                             rows=len(batch)) as sp:
+                if fwds and any(f is not None for f in fwds):
+                    outs = eng.decode_stage(items, fwds=fwds)
+                else:
+                    outs = eng.decode_stage(items)
             # straggler telemetry: wall seconds per batched token, per node
-            self.node_decode_s[node] += time.monotonic() - t_pass
+            self.node_decode_s[node] += sp.t1 - sp.t0
             self.node_decode_tokens[node] += sum(
                 w.get("nt", 1) for w in batch)
-            for w, out in zip(batch, outs):
-                job, si, epoch, j = w["job"], w["si"], w["epoch"], w["j"]
-                if si == len(job.pipe.stages) - 1:
-                    if w.get("spec"):
-                        # verify pass: no sampling, no node-side launch —
-                        # the greedy argmax vector (one per verified
-                        # position; identical to what sample() computes at
-                        # temperature <= 0) returns to the coordinator,
-                        # which owns acceptance and rollback
-                        greedy = np.asarray(
-                            np.argmax(np.asarray(out.logits), axis=-1),
-                            np.int32).reshape(-1)
-                        self._send(node, COORDINATOR, (j, greedy),
-                                   len(greedy) * self.profile.token_bytes,
-                                   lambda p, jb=job, e=epoch:
-                                   self._on_spec_result(jb, e, p[0], p[1]))
-                        continue
-                    tok = eng.sample(out.logits, job.req.temperature)
-                    self._send(node, COORDINATOR, (j, tok),
-                               self.profile.token_bytes,
+            with TRACER.span("helix.sample", rows=len(batch)):
+                self._route_outputs(node, eng, batch, outs)
+
+    def _route_outputs(self, node: str, eng, batch: List[dict],
+                       outs) -> None:
+        """After a decode call: sample at the final stage and send each
+        token to the coordinator (launching the next pass), or send the
+        activations on to the next stage."""
+        for w, out in zip(batch, outs):
+            job, si, epoch, j = w["job"], w["si"], w["epoch"], w["j"]
+            if si == len(job.pipe.stages) - 1:
+                if w.get("spec"):
+                    # verify pass: no sampling, no node-side launch —
+                    # the greedy argmax vector (one per verified
+                    # position; identical to what sample() computes at
+                    # temperature <= 0) returns to the coordinator,
+                    # which owns acceptance and rollback
+                    greedy = np.asarray(
+                        np.argmax(np.asarray(out.logits), axis=-1),
+                        np.int32).reshape(-1)
+                    self._send(node, COORDINATOR, (j, greedy),
+                               len(greedy) * self.profile.token_bytes,
                                lambda p, jb=job, e=epoch:
-                               self._on_decode_token(jb, e, p[0], p[1]))
-                    # speculative: token j leaves for the coordinator while
-                    # the pass for j+1 leaves for stage 0
-                    self._maybe_launch(job, node, tok, j + 1)
-                else:
-                    nxt = job.pipe.stages[si + 1].node
-                    n = w.get("nt", 1)
-                    self._send(node, nxt, out.h, self._act_bytes(n),
-                               lambda h, jb=job, e=epoch, s=si + 1,
-                               p=w["pos"], jj=j, sp=w.get("spec", False),
-                               nn=n:
-                               self._enqueue_decode(jb, e, s, 0, h, p, jj,
-                                                    spec=sp, nt=nn))
+                               self._on_spec_result(jb, e, p[0], p[1]))
+                    continue
+                tok = eng.sample(out.logits, job.req.temperature)
+                self._send(node, COORDINATOR, (j, tok),
+                           self.profile.token_bytes,
+                           lambda p, jb=job, e=epoch:
+                           self._on_decode_token(jb, e, p[0], p[1]))
+                # speculative: token j leaves for the coordinator while
+                # the pass for j+1 leaves for stage 0
+                self._maybe_launch(job, node, tok, j + 1)
+            else:
+                nxt = job.pipe.stages[si + 1].node
+                n = w.get("nt", 1)
+                self._send(node, nxt, out.h, self._act_bytes(n),
+                           lambda h, jb=job, e=epoch, s=si + 1,
+                           p=w["pos"], jj=j, sp=w.get("spec", False),
+                           nn=n:
+                           self._enqueue_decode(jb, e, s, 0, h, p, jj,
+                                                spec=sp, nt=nn))
 
     # -- completion / preemption ---------------------------------------------
     def _release_all(self, job: _Job) -> None:
@@ -1521,6 +1552,7 @@ class ClusterRuntime:
     def _preempt(self, job: _Job) -> None:
         """Pool exhausted: evict pipeline-wide, keep generated tokens, requeue
         at the front (recompute-on-readmit, same pipeline)."""
+        TRACER.count("preemptions")
         self._requeue(job, clear_pipe=False)
 
     # -- failover ------------------------------------------------------------
@@ -1557,6 +1589,7 @@ class ClusterRuntime:
             job.route = None
         self.jobs.pop(job.req.request_id, None)
         job.req.preemptions += 1
+        job.t_queued = TRACER.clock()
         self.queue.appendleft(job)
 
     def apply_plan(self, plan) -> None:

@@ -51,6 +51,7 @@ from ..models.stage import (stage_absorb_dense_prefill, stage_blocks,
 from .engine import EngineConfig, _active_blocks_bucket
 from .kv_pool import PagePool, full_rectangle_pages
 from .sampling import sample_token
+from .trace import TRACER
 
 
 @dataclasses.dataclass
@@ -116,6 +117,7 @@ class _StageEngineBase:
         self.slots: List[Optional[int]] = [None] * engine_cfg.max_batch
         self._scratch = engine_cfg.max_batch   # padding row, never allocated
         self._rng = np.random.RandomState(rng_seed)
+        self.decode_calls = 0                  # index of the next decode step
 
     def _put(self, tree):
         """Commit ``tree`` (host or device arrays) to this engine's device."""
@@ -188,6 +190,23 @@ class _StageEngineBase:
         return self._put((idx, tok, pos, entry, h_in))
 
     # -- decode orchestration ---------------------------------------------
+    def _decode_span(self, items: List[DecodeItem]):
+        """The ``helix.engine.decode`` span of one step: its call index and
+        rows, and (in memory only) each row's context after the step."""
+        i = self.decode_calls
+        self.decode_calls += 1
+        return TRACER.span("helix.engine.decode", call=i, rows=len(items),
+                           ctx=tuple(it.pos + 1 for it in items))
+
+    @staticmethod
+    def _fetch(*outs):
+        """Wait for a step's outputs on the device, then copy them to the
+        host (``None`` stays ``None``)."""
+        with TRACER.span("helix.engine.wait"):
+            jax.block_until_ready(outs)
+        with TRACER.span("helix.engine.fetch"):
+            return tuple(None if o is None else np.asarray(o) for o in outs)
+
     def _decode_step(self, items: List[DecodeItem]):
         """One batched single-token decode step.  Returns (h, logits) as
         numpy arrays of shape (len(items), 1, d) and (len(items), V) (or
@@ -306,13 +325,15 @@ class StageEngine(_StageEngineBase):
         return np.asarray(out)[0] if self.is_last else np.asarray(out)
 
     def _decode_step(self, items: List[DecodeItem]):
-        idx, tok, pos, entry, h_in = self._assemble(items)
-        h, logits, self.caches = self._decode(self.sparams, self.caches, tok,
-                                              h_in, entry, pos, idx)
-        for it in items:
-            self._active_tokens[it.slot] = it.pos + 1
-        return (np.asarray(h),
-                np.asarray(logits) if logits is not None else None)
+        with self._decode_span(items):
+            with TRACER.span("helix.engine.inputs"):
+                idx, tok, pos, entry, h_in = self._assemble(items)
+            with TRACER.span("helix.engine.launch"):
+                h, logits, self.caches = self._decode(
+                    self.sparams, self.caches, tok, h_in, entry, pos, idx)
+            for it in items:
+                self._active_tokens[it.slot] = it.pos + 1
+            return self._fetch(h, logits)
 
     def rollback(self, slot: int, tokens: int) -> None:
         """Dense caches are positional and attention masks rows >= pos, so
@@ -446,22 +467,27 @@ class PagedStageEngine(_StageEngineBase):
         """One prompt chunk through the slice (all-paged stacks).  x: (C,)
         tokens or (1, C, d) activations.  Returns chunk activations
         (1, C, d), or last-token logits (V,) at the final stage."""
-        if entry == 0:
-            xin = self._put(np.asarray(x, np.int32)[None, :])
-        else:
-            xin = self._put(x)
-        C = xin.shape[1]
-        tb = self._put(self.pool.table[:, slot:slot + 1])
-        n_act = _active_blocks_bucket(start + C, self.pool.page,
-                                      self.pool.blocks_per_seq)
+        C = len(x) if entry == 0 else x.shape[1]
         pool = self.pool
-        out, pool.k, pool.v, pool.k_scales, pool.v_scales = \
-            self._prefill_chunk(
-                self.sparams, xin, entry,
-                self._put(np.asarray([start], np.int32)),
-                pool.k, pool.v, pool.k_scales, pool.v_scales, tb,
-                n_act=n_act)
-        return np.asarray(out)[0] if self.is_last else np.asarray(out)
+        with TRACER.span("helix.engine.prefill", request=self.slots[slot],
+                         start=int(start), width=int(C)):
+            with TRACER.span("helix.engine.inputs"):
+                if entry == 0:
+                    xin = self._put(np.asarray(x, np.int32)[None, :])
+                else:
+                    xin = self._put(x)
+                tb = self._put(pool.table[:, slot:slot + 1])
+                start_in = self._put(np.asarray([start], np.int32))
+            n_act = _active_blocks_bucket(start + C, pool.page,
+                                          pool.blocks_per_seq)
+            with TRACER.span("helix.engine.launch"):
+                out, pool.k, pool.v, pool.k_scales, pool.v_scales = \
+                    self._prefill_chunk(
+                        self.sparams, xin, entry, start_in,
+                        pool.k, pool.v, pool.k_scales, pool.v_scales, tb,
+                        n_act=n_act)
+            out, = self._fetch(out)
+        return out[0] if self.is_last else out
 
     def prefill_stage(self, slot: int, x, entry: int):
         """Single-shot prompt pass (hybrid stacks): dense prefill of the
@@ -554,15 +580,17 @@ class PagedStageEngine(_StageEngineBase):
 
     # -- decode ----------------------------------------------------------
     def _decode_step(self, items: List[DecodeItem]):
-        idx, tok, pos, entry, h_in = self._assemble(items)
-        tables = self._put(self.pool.table)
         pool = self.pool
-        (h, logits, self.caches, pool.k, pool.v,
-         pool.k_scales, pool.v_scales) = self._decode(
-            self.sparams, self.caches, tok, h_in, entry, pos, idx,
-            pool.k, pool.v, pool.k_scales, pool.v_scales, tables)
-        return (np.asarray(h),
-                np.asarray(logits) if logits is not None else None)
+        with self._decode_span(items):
+            with TRACER.span("helix.engine.inputs"):
+                idx, tok, pos, entry, h_in = self._assemble(items)
+                tables = self._put(pool.table)
+            with TRACER.span("helix.engine.launch"):
+                (h, logits, self.caches, pool.k, pool.v,
+                 pool.k_scales, pool.v_scales) = self._decode(
+                    self.sparams, self.caches, tok, h_in, entry, pos, idx,
+                    pool.k, pool.v, pool.k_scales, pool.v_scales, tables)
+            return self._fetch(h, logits)
 
     # -- speculative rollback --------------------------------------------
     def _spec_begin(self, it: DecodeItem) -> None:
