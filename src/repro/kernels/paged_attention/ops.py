@@ -27,15 +27,15 @@ def paged_attention_op(q, k_pages, v_pages, block_tables, lengths,
 
 
 def streamed_pages_per_step(lengths, page: int) -> int:
-    """Pages the variable-context kernel copies HBM->VMEM per launch.
+    """Pages the kernel copies HBM->VMEM per launch.
 
-    The grid stays (B, NP), but the clamped index map re-issues the last
-    active page index past ``ceil(len/page)`` and Pallas elides copies whose
-    index matches the previous grid step — so traffic follows the *live*
-    context: ``sum_b max(ceil(len_b / page), 1)`` pages (the fixed-grid
-    kernel streamed ``B * NP``)."""
+    A sequence's pages are gathered in blocks, and the kernel loops over its
+    live blocks only: the last block copies only the pages below
+    ``ceil(len/page)``, so traffic follows the *live* context,
+    ``sum_b ceil(len_b / page)`` pages, not ``B * NP``; an empty sequence
+    copies none."""
     l = np.asarray(lengths)
-    return int(np.maximum(-(-l // page), 1).sum())
+    return int((-(-l // page)).sum())
 
 
 def dense_to_pages(k: jax.Array, v: jax.Array, lengths, page: int

@@ -92,6 +92,8 @@ PAGED_SHAPES = [
     (3, 8, 2, 256, 64, 128),                 # GQA
     (1, 4, 1, 512, 128, 64),                 # MQA
     (4, 2, 2, 128, 32, 128),
+    (2, 16, 16, 192, 16, 128),               # OLMo-1B heads; NP=12, 8 a block
+    (2, 15, 5, 128, 16, 64),                 # SmolLM-360M: 2 records a row
 ]
 
 
@@ -211,3 +213,88 @@ def test_paged_attention_matches_dense_decode():
     ref = jnp.einsum("bhgs,bshd->bhgd", p, v).reshape(B, H, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+# --- paged attention: edge lengths and dead pages -----------------------------
+
+# B rows of the edge lengths below; NP = 12 table entries of page 16, so
+# the kernel's 8-page blocks split each table into a full and a partial one
+EDGE_LAYOUTS = {
+    # H, KH, D
+    "olmo_mha": (16, 16, 128),
+    "gqa": (8, 2, 128),
+    "mqa": (4, 1, 64),
+}
+EDGE_PAGE, EDGE_NP = 16, 12
+
+
+def _edge_inputs(layout, dtype, seed):
+    """Rows of length 1, a page, one block, one block -1 and +1, the whole
+    table, and an empty row between them, over a pool whose pages are
+    scattered and shared by no row."""
+    from repro.kernels.paged_attention.kernel import pages_per_block
+    H, KH, D = EDGE_LAYOUTS[layout]
+    page, NP = EDGE_PAGE, EDGE_NP
+    itemsize = 1 if dtype == "int8" else jnp.dtype(dtype).itemsize
+    blk = page * pages_per_block(page, NP, page * KH * D * itemsize)
+    lens = [1, page, blk, 0, blk - 1, blk + 1, NP * page]
+    B = len(lens)
+    P = B * NP + 1
+    k1, k2, k3, k4 = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(k1, (B, H, D), jnp.float32)
+    k = jax.random.normal(k2, (P, page, KH, D), jnp.float32)
+    v = jax.random.normal(k3, (P, page, KH, D), jnp.float32)
+    tables = jax.random.permutation(k4, P)[:B * NP].reshape(B, NP)
+    lengths = jnp.asarray(lens, jnp.int32)
+    if dtype == "int8":
+        (k, ks), (v, vs) = quantize_kv_pages(k), quantize_kv_pages(v)
+        return q, k, v, tables.astype(jnp.int32), lengths, ks, vs
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype),
+            tables.astype(jnp.int32), lengths, None, None)
+
+
+def _check_edge(out, q, k, v, tables, lengths, ks, vs, dtype):
+    ref = paged_attention_ref(q, k, v, tables, lengths,
+                              k_scales=ks, v_scales=vs)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    live = np.asarray(lengths) > 0
+    tol = TOL[jnp.bfloat16] if dtype == jnp.bfloat16 else TOL[jnp.float32]
+    np.testing.assert_allclose(out[live], ref[live], **tol)
+    assert np.all(out[~live] == 0.0)         # an empty row attends nothing
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "int8"],
+                         ids=["f32", "bf16", "int8"])
+@pytest.mark.parametrize("layout", sorted(EDGE_LAYOUTS))
+def test_paged_attention_edge_lengths(layout, dtype):
+    """Lengths at every page and block boundary, through the same kernel
+    for MHA, GQA and MQA heads in float32, bfloat16 and int8 pages."""
+    q, k, v, tables, lengths, ks, vs = _edge_inputs(layout, dtype, 5)
+    out = paged_attention(q, k, v, tables, lengths, k_scales=ks,
+                          v_scales=vs, interpret=True)
+    _check_edge(out, q, k, v, tables, lengths, ks, vs, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, "int8"],
+                         ids=["bf16", "int8"])
+def test_paged_attention_never_reads_dead_pages(dtype):
+    """Every page outside a row's live set is NaN (an int8 page's scales):
+    the output still equals the oracle on the clean pool and is finite, so
+    no dead page was read — not even those in a row's last block."""
+    q, k, v, tables, lengths, ks, vs = _edge_inputs("olmo_mha", dtype, 6)
+    live = np.zeros(k.shape[0], bool)
+    for row, n in zip(np.asarray(tables), np.asarray(lengths)):
+        live[row[:-(-int(n) // EDGE_PAGE)]] = True
+    dead = jnp.asarray(~live)
+    if dtype == "int8":
+        pk, pv = k, v
+        pks = jnp.where(dead[:, None], jnp.nan, ks)
+        pvs = jnp.where(dead[:, None], jnp.nan, vs)
+    else:
+        pk = jnp.where(dead[:, None, None, None], jnp.nan, k)
+        pv = jnp.where(dead[:, None, None, None], jnp.nan, v)
+        pks = pvs = None
+    out = paged_attention(q, pk, pv, tables, lengths, k_scales=pks,
+                          v_scales=pvs, interpret=True)
+    assert np.all(np.isfinite(np.asarray(out, np.float32)))
+    _check_edge(out, q, k, v, tables, lengths, ks, vs, dtype)

@@ -123,7 +123,7 @@ RAGGED = [
 
 @pytest.mark.parametrize("page,lens", RAGGED)
 def test_variable_context_ragged_exact(page, lens):
-    """Clamped index_map drops no live token and leaks no dead one."""
+    """Live-block gathering drops no live token and leaks no dead one."""
     B = len(lens)
     H, KH, D = 4, 2, 64
     S = max(-(-max(lens) // page), 1) * page
@@ -160,8 +160,8 @@ def test_streamed_pages_live_only():
     assert live < len(lens) * blocks_per_seq
     full = np.full((4,), 8 * page, np.int32)
     assert streamed_pages_per_step(full, page) == 4 * 8
-    # empty sequences still stream their single clamped page
-    assert streamed_pages_per_step(np.zeros((2,), np.int32), page) == 2
+    # empty sequences stream nothing
+    assert streamed_pages_per_step(np.zeros((2,), np.int32), page) == 0
 
 
 # --- quantized append --------------------------------------------------------

@@ -1,9 +1,9 @@
 """Main-path programs compiled for a described TPU v5e chip.
 
 Nothing runs: the TPU compiler, which is installed even where no chip is
-attached, compiles the decode kernel and the stage engine's steps at
-SmolLM-360M's published widths for one chip of a described ``v5e:2x2``
-topology.  This catches what interpret mode cannot — block shapes the
+attached, compiles the decode kernel at SmolLM-360M's and OLMo-1B's
+published widths, and the stage engine's steps at SmolLM-360M's, for one
+chip of a described ``v5e:2x2`` topology.  This catches what interpret mode cannot — block shapes the
 TPU's tiling refuses, programs that do not fit the chip — at no chip time.
 
 The topology is described inside a module fixture (never at import): only
@@ -83,11 +83,24 @@ def _pool_shapes(sharding, quantized):
     return pages, scales
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_paged_attention_compiles(one_chip, quantized):
-    B, H, KH, D = MAX_BATCH, CFG.num_heads, CFG.num_kv_heads, \
-        CFG.resolved_head_dim
-    pages, scales = _pool_shapes(one_chip, quantized)
+# (config, decode rows, pool pages): SmolLM-360M's one-node engine above;
+# OLMo-1B as the benchmark serves it, 32 slots and the scratch row over a
+# full-rectangle pool of 65,537 pages
+KERNEL_SHAPES = {"smollm_360m": (CFG, MAX_BATCH, NUM_PAGES),
+                 "olmo_1b": (get_config("olmo_1b"), 33, 65537)}
+
+
+@pytest.mark.parametrize("arch,quantized", [
+    ("smollm_360m", False), ("smollm_360m", True),
+    ("olmo_1b", False), ("olmo_1b", True)],
+    ids=["bf16", "int8", "olmo_1b-bf16", "olmo_1b-int8"])
+def test_paged_attention_compiles(one_chip, arch, quantized):
+    cfg, B, num_pages = KERNEL_SHAPES[arch]
+    H, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    dt = jnp.int8 if quantized else jnp.bfloat16
+    pages = _spec(one_chip, (num_pages, PAGE, KH, D), dt)
+    scales = _spec(one_chip, (num_pages, KH), jnp.float32) if quantized \
+        else None
     q = _spec(one_chip, (B, H, D), jnp.bfloat16)
     tables = _spec(one_chip, (B, NP), jnp.int32)
     lengths = _spec(one_chip, (B,), jnp.int32)
