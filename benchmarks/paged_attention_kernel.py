@@ -5,8 +5,10 @@ row), 16 MHA heads of 128, page 16, NP = 128 table entries, bf16 pages in a
 full 65,537-page pool.  The 32 live rows hold contexts drawn from the Azure
 lengths of ``bench/traffic/batch.json`` (a prompt, plus a uniform share of
 its output already decoded); the scratch row holds one token on page 0.
-Each row's pages are drawn at random from the pool.  ``--shapes smollm``
-takes SmolLM-360M's heads instead (15 query heads on 5 kv heads of 64).
+Each row's pages are drawn at random from the pool, which is stored as
+``layout.page_layout`` lays it out (``(P, 256, 128)`` for OLMo-1B).
+``--shapes smollm`` takes SmolLM-360M's heads instead (15 query heads on 5
+kv heads of 64: pages of 40 rows of two records).
 
 For each candidate it prints us per call (median over repeats of
 back-to-back calls of the jitted op, any pool relayout XLA adds included,
@@ -24,6 +26,7 @@ shapes in interpret mode to check the script, and prints no timing.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -40,6 +43,7 @@ import numpy as np                                      # noqa: E402
 import traffic                                          # noqa: E402
 from repro.kernels.paged_attention import kernel as pa  # noqa: E402
 from repro.kernels.paged_attention import paged_attention_ref as pa_ref  # noqa: E402,E501
+from repro.kernels.paged_attention.layout import page_layout  # noqa: E402
 
 PPB = (4, 8, 16, 32)
 HEADS = {"olmo": dict(H=16, KH=16, D=128), "smollm": dict(H=15, KH=5, D=64)}
@@ -63,20 +67,21 @@ def inputs(seed, B, H, KH, D, page, NP, pool_pages, max_len):
                              np.zeros((1, NP), ids.dtype)]).astype(np.int32)
     key = jax.random.key(seed % (2 ** 31))
     kq, kk, kv = jax.random.split(key, 3)
-    shape = (pool_pages, page, KH, D)
+    shape = page_layout(page, KH, D).shape(pool_pages)
     k = jax.random.normal(kk, shape, jnp.bfloat16)
     v = jax.random.normal(kv, shape, jnp.bfloat16)
     q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
     return q, k, v, jnp.asarray(tables), jnp.asarray(lengths)
 
 
-def candidates(interpret: bool, sweep: bool):
+def candidates(interpret: bool, sweep: bool, page: int):
     """(name, fn(q, k, v, tables, lengths)) for every candidate."""
-    out = [("derived", lambda *a: pa.paged_attention(*a, interpret=interpret))]
+    out = [("derived", lambda *a: pa.paged_attention(
+        *a, page=page, interpret=interpret))]
     if sweep and hasattr(pa, "_paged_attention"):
         for ppb in PPB:
             out.append((f"ppb{ppb}", lambda *a, ppb=ppb: pa._paged_attention(
-                *a, k_scales=None, v_scales=None, ppb=ppb,
+                *a, page=page, k_scales=None, v_scales=None, ppb=ppb,
                 interpret=interpret)))
     return out
 
@@ -120,8 +125,9 @@ def main() -> None:
     page, KH, D = dims["page"], dims["KH"], dims["D"]
     pages = int(np.sum(-(-np.asarray(lengths) // page)))
     live = 2 * pages * page * KH * D * 2
-    ref = np.asarray(jax.jit(pa_ref)(q, k, v, tables, lengths), np.float32)
-    for name, fn in candidates(a.rehearse, a.shapes == "olmo"):
+    ref = np.asarray(jax.jit(functools.partial(pa_ref, page=page))(
+        q, k, v, tables, lengths), np.float32)
+    for name, fn in candidates(a.rehearse, a.shapes == "olmo", page):
         args = (q, k, v, tables, lengths)
         try:
             out = np.asarray(jax.jit(fn)(*args), np.float32)

@@ -61,6 +61,7 @@ class ModelConfig:
     # --- norms / embeddings ---
     mlp_kind: str = "gated"                # gated (SwiGLU) | plain (GELU)
     norm: str = "rmsnorm"                  # rmsnorm | layernorm | nonparam_ln
+    rms_norm_eps: float = 1e-6
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
     max_position: int = 1 << 20

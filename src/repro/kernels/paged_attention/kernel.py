@@ -24,15 +24,14 @@ VMEM budget for the two slots of K and V.  On one TPU v5e at the OLMo-1B
 batch cell's shapes (``benchmarks/paged_attention_kernel.py``), 128 tokens
 a block take the same time as 256 or 512, and 64 are ~10% slower.
 
-Page rows.  The wrapper views the ``(P, page, KH, D)`` pool as
+Page rows.  The pools come stored as ``layout.page_layout`` lays them out,
 ``(P, R, L)``: rows of ``L = lcm(D, 128)`` lanes, each holding ``k = L/D``
-consecutive (token, kv head) records.  For the usual ``D = 128`` this is
-the pool itself (``R = page*KH``, ``k = 1``; XLA makes it a bitcast); for
-SmolLM-360M's 5 kv heads of 64 it packs two records a row, so a page is
-whole (8, 128) tiles and one DMA can slice it (Mosaic refuses to slice a
-page whose ``(KH, D)`` is not whole tiles).  XLA keeps such pools
-pages-minor and relayouts them for the kernel on every call; in the row
-view that copy carries no padding.
+consecutive (token, kv head) records, tokens major.  For the
+usual ``D = 128`` a row is one record (``R = page*KH``); SmolLM-360M's 5
+kv heads of 64 pack two records a row (``R = 40`` at page 16).  The rule
+only admits pages of whole ``(8, 128)`` tiles, which the chip keeps in the
+written order, so one DMA copies a page as it lies and no program
+relayouts the pool.
 
 Compute, on the block as it landed: the ``(ppb, R, L)`` block is viewed as
 ``(ppb*R, L)`` (a free reshape: no relayout, and no dot_general batched
@@ -63,6 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .layout import pool_layout
 
 NEG_INF = -1e30
 BLOCK_TOKENS = 128
@@ -206,42 +207,38 @@ def _kernel(block_tables, lengths, q_ref, *refs, page: int, num_pages: int,
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, lengths: jax.Array, *,
-                    k_scales: jax.Array | None = None,
+                    page: int, k_scales: jax.Array | None = None,
                     v_scales: jax.Array | None = None,
                     interpret: bool = False) -> jax.Array:
-    """q: (B,H,D); k/v_pages: (P,page,KH,D); block_tables: (B,NP);
-    lengths: (B,) -> (B,H,D).
+    """q: (B,H,D); k/v_pages: (P,R,L) stored pages of ``page`` tokens;
+    block_tables: (B,NP); lengths: (B,) -> (B,H,D).
 
     ``k_scales``/``v_scales``: optional (P, KH) float32 per-page per-kv-head
     absmax scales — when given, pages are int8 and dequantized in-VMEM.
     A sequence of length 0 attends to nothing and gets zeros.
     """
-    _, page, KH, D = k_pages.shape
-    page_bytes = page * KH * D * k_pages.dtype.itemsize
-    ppb = pages_per_block(page, block_tables.shape[1], page_bytes)
+    lay = pool_layout(k_pages.shape, q.shape[-1], page=page)
+    ppb = pages_per_block(page, block_tables.shape[1],
+                          lay.page_bytes(k_pages.dtype.itemsize))
     return _paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                            k_scales=k_scales, v_scales=v_scales, ppb=ppb,
-                            interpret=interpret)
+                            page=page, k_scales=k_scales, v_scales=v_scales,
+                            ppb=ppb, interpret=interpret)
 
 
-def _paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
+def _paged_attention(q, k_pages, v_pages, block_tables, lengths, *, page,
                      k_scales, v_scales, ppb: int, interpret: bool):
     """``paged_attention`` with ``ppb`` pages a block."""
     B, H, D = q.shape
-    P, page, KH, _ = k_pages.shape
+    P, R, L = k_pages.shape
     NP = block_tables.shape[1]
+    lay = pool_layout(k_pages.shape, D, page=page)
+    KH, k = lay.kv_heads, lay.records      # k: records in a page row
+    if H % KH:
+        raise ValueError(f"{H} query heads over {KH} kv heads")
     G = H // KH
     quantized = k_scales is not None
     if quantized and v_scales is None:
         raise ValueError("k_scales given without v_scales")
-    L = math.lcm(D, 128)
-    k = L // D                             # records in a page row
-    R = page * KH * D // L
-    if R * L != page * KH * D:
-        raise ValueError(f"a page of {page}x{KH}x{D} is not whole rows "
-                         f"of {L} lanes")
-    k_pages = k_pages.reshape(P, R, L)
-    v_pages = v_pages.reshape(P, R, L)
     GK = G * KH
     GKp = -(-GK // SUBLANES) * SUBLANES    # one record stack, whole tiles
     # stack h, row g*KH + kh: query head kh*G + g in lanes [h*D, (h+1)*D)

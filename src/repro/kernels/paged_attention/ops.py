@@ -7,22 +7,23 @@ tables, admission control) lives in ``repro.serving.kv_pool.PagePool``;
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .kernel import paged_attention
+from .layout import page_layout
 from .ref import paged_attention_ref
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("page", "interpret"))
 def paged_attention_op(q, k_pages, v_pages, block_tables, lengths,
-                       k_scales=None, v_scales=None, *,
+                       k_scales=None, v_scales=None, *, page: int,
                        interpret: bool = False):
     return paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                           k_scales=k_scales, v_scales=v_scales,
+                           page=page, k_scales=k_scales, v_scales=v_scales,
                            interpret=interpret)
 
 
@@ -40,12 +41,14 @@ def streamed_pages_per_step(lengths, page: int) -> int:
 
 def dense_to_pages(k: jax.Array, v: jax.Array, lengths, page: int
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Pack dense (B,S,KH,D) caches into a page pool + block tables
-    (testing/migration helper; a real server allocates pages on demand)."""
+    """Pack dense (B,S,KH,D) caches into a page pool stored as
+    ``layout.page_layout`` lays it out, plus block tables (testing/migration
+    helper; a real server allocates pages on demand)."""
     B, S, KH, D = k.shape
     assert S % page == 0
     npages = S // page
-    k_pages = k.reshape(B * npages, page, KH, D)
-    v_pages = v.reshape(B * npages, page, KH, D)
+    lay = page_layout(page, KH, D)
+    k_pages = lay.to_rows(k.reshape(B * npages, page, KH, D))
+    v_pages = lay.to_rows(v.reshape(B * npages, page, KH, D))
     block_tables = jnp.arange(B * npages, dtype=jnp.int32).reshape(B, npages)
     return k_pages, v_pages, block_tables

@@ -1,10 +1,13 @@
 """Int8 KV-page quantization: per-page, per-kv-head absmax scales.
 
 Same absmax idiom as ``dist.collectives.quantize_int8`` (the compressed
-pipeline-parallel collectives), but at page granularity: a (P, page, KH, D)
-pool quantizes to int8 with one float32 scale per (page, kv_head) — K and V
-separately — so the decode kernel dequantizes in-VMEM with a (KH,) scale row
-that rides the same scalar-prefetched block-table index as the page itself.
+pipeline-parallel collectives), but at page granularity: pages seen as
+tokens, (..., page, KH, D), quantize to int8 with one float32 scale per
+(page, kv_head) — K and V separately — so the decode kernel dequantizes
+in-VMEM with a (KH,) scale row that rides the same block-table index as the
+page itself.  The pool stores the int8 pages as ``layout.page_layout``
+lays them out, (P, R, L) rows; ``quantized_append`` reads and writes them
+so.
 
 Appends are read-modify-write at page granularity (``quantized_append``):
 the touched window of pages is gathered, dequantized, the new rows inserted,
@@ -22,6 +25,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from .layout import pool_layout
 
 _TINY = 1e-8
 
@@ -49,8 +54,9 @@ def quantized_append(pages: jax.Array, scales: jax.Array,
     """Append ``rows`` (B, C, KH, D) at contiguous positions
     ``start .. start+C-1`` of each sequence's paged KV.
 
-    pages: (P, page, KH, D) int8; scales: (P, KH) f32; block_table: (B, NP)
-    int32; start: (B,) int32.  Returns (pages, scales) updated.
+    pages: (P, R, L) int8, stored as ``layout.page_layout`` lays them out;
+    scales: (P, KH) f32; block_table: (B, NP) int32; start: (B,) int32.
+    Returns (pages, scales) updated.
 
     The touched window is at most ``1 + ceil((C-1+page-1)/page)`` pages per
     row (static), gathered with the straddle handled by masking: window
@@ -60,8 +66,9 @@ def quantized_append(pages: jax.Array, scales: jax.Array,
     requantization (stale data from a page's previous owner must not inflate
     the fresh scale).
     """
-    P, page, KH, D = pages.shape
-    B, C = rows.shape[:2]
+    B, C, KH, D = rows.shape
+    lay = pool_layout(pages.shape, D, kv_heads=KH)
+    page = lay.page
     NP = block_table.shape[1]
     NT = 1 + (C + page - 2) // page          # touched pages incl. straddle
     loc0 = start // page                     # (B,) first touched block
@@ -72,7 +79,8 @@ def quantized_append(pages: jax.Array, scales: jax.Array,
     pids = jnp.take_along_axis(block_table, jnp.clip(locs, 0, NP - 1), axis=1)
     pids = jnp.where(valid, pids, 0)                          # (B, NT)
 
-    win = dequantize_kv_pages(pages[pids], scales[pids])      # (B,NT,pg,KH,D)
+    win = dequantize_kv_pages(lay.to_tokens(pages[pids]),
+                              scales[pids])                   # (B,NT,pg,KH,D)
     win = win.reshape(B, NT * page, KH, D)
     gpos = loc0[:, None] * page + jnp.arange(NT * page)[None, :]
     win = jnp.where((gpos < start[:, None] + C)[..., None, None], win, 0.0)
@@ -80,6 +88,7 @@ def quantized_append(pages: jax.Array, scales: jax.Array,
     win = win.at[jnp.arange(B)[:, None], idx].set(rows.astype(jnp.float32))
 
     qw, sw = quantize_kv_pages(win.reshape(B, NT, page, KH, D))
-    pages = pages.at[pids.reshape(-1)].set(qw.reshape(-1, page, KH, D))
+    pages = pages.at[pids.reshape(-1)].set(
+        lay.to_rows(qw).reshape(-1, lay.rows, lay.lanes))
     scales = scales.at[pids.reshape(-1)].set(sw.reshape(-1, KH))
     return pages, scales

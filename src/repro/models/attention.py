@@ -16,6 +16,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.paged_attention.layout import pool_layout, write_tokens
 from .common import ParamSpec, apply_rope, rope_angles
 
 NEG_INF = -1e30
@@ -263,14 +264,32 @@ def _gqa_qkv_rope(cfg, params, x, positions):
     return q, k, v
 
 
+def _append_kv(k_pages, v_pages, k_scales, v_scales, block_table, start,
+               k_new, v_new, page):
+    """Write (B, C, KH, D) K/V at positions ``start..start+C-1`` of each
+    row's pages: int8 pools requantize the pages touched, others write the
+    runs of whole tiles touched (``layout.write_tokens``)."""
+    if k_scales is not None:
+        from ..kernels.paged_attention import quantized_append
+        k_pages, k_scales = quantized_append(k_pages, k_scales, block_table,
+                                             start, k_new)
+        v_pages, v_scales = quantized_append(v_pages, v_scales, block_table,
+                                             start, v_new)
+    else:
+        k_pages = write_tokens(k_pages, block_table, start, k_new, page)
+        v_pages = write_tokens(v_pages, block_table, start, v_new, page)
+    return k_pages, v_pages, k_scales, v_scales
+
+
 def gqa_decode_paged(cfg, params, x, k_pages, v_pages, block_table, cache_pos,
                      *, k_scales=None, v_scales=None, interpret=False):
     """Single-token decode against a shared page pool.
 
-    x: (B,1,d); k/v_pages: (P,page,KH,D) pool shared across layers;
-    block_table: (B,NP) page ids for this layer; cache_pos: (B,) absolute
-    position of the token being generated.  Writes the new K/V into the page
-    holding ``cache_pos`` and runs the Pallas paged_attention kernel over the
+    x: (B,1,d); k/v_pages: (P,R,L) pool shared across layers, stored as
+    ``kernels.paged_attention.layout`` lays it out; block_table: (B,NP) page
+    ids for this layer; cache_pos: (B,) absolute position of the token
+    being generated.  Writes the new K/V into the page holding
+    ``cache_pos`` and runs the Pallas paged_attention kernel over the
     sequence's pages.  ``k_scales``/``v_scales``: (P, KH) f32 when the pool
     is int8 (per-page per-kv-head absmax; the append requantizes the touched
     page and the kernel dequantizes in-VMEM).  Returns
@@ -278,23 +297,14 @@ def gqa_decode_paged(cfg, params, x, k_pages, v_pages, block_table, cache_pos,
     """
     from ..kernels.paged_attention import paged_attention_op
 
-    B = x.shape[0]
-    page = k_pages.shape[1]
+    page = pool_layout(k_pages.shape, cfg.resolved_head_dim,
+                       kv_heads=cfg.num_kv_heads).page
     q, k_new, v_new = _gqa_qkv_rope(cfg, params, x, cache_pos[:, None])
-    if k_scales is not None:
-        from ..kernels.paged_attention import quantized_append
-        k_pages, k_scales = quantized_append(k_pages, k_scales, block_table,
-                                             cache_pos, k_new)
-        v_pages, v_scales = quantized_append(v_pages, v_scales, block_table,
-                                             cache_pos, v_new)
-    else:
-        pid = jnp.take_along_axis(block_table, (cache_pos // page)[:, None],
-                                  axis=1)[:, 0]
-        off = cache_pos % page
-        k_pages = k_pages.at[pid, off].set(k_new[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[pid, off].set(v_new[:, 0].astype(v_pages.dtype))
+    k_pages, v_pages, k_scales, v_scales = _append_kv(
+        k_pages, v_pages, k_scales, v_scales, block_table, cache_pos,
+        k_new, v_new, page)
     ctx = paged_attention_op(q[:, 0], k_pages, v_pages, block_table,
-                             cache_pos + 1, k_scales, v_scales,
+                             cache_pos + 1, k_scales, v_scales, page=page,
                              interpret=interpret)
     out = jnp.einsum("bshk,hkd->bsd", ctx[:, None].astype(x.dtype),
                      params["o"])
@@ -320,30 +330,25 @@ def gqa_prefill_paged(cfg, params, x, k_pages, v_pages, block_table,
     Returns (out (B,C,d), k_pages, v_pages, k_scales, v_scales).
     """
     B, C, d = x.shape
-    P, page, KH, D = k_pages.shape
+    KH, D = cfg.num_kv_heads, cfg.resolved_head_dim
+    lay = pool_layout(k_pages.shape, D, kv_heads=KH)
+    page = lay.page
     NP = block_table.shape[1]
     H = cfg.num_heads
     G = H // KH
     nact = NP if active_blocks is None else max(1, min(active_blocks, NP))
     q, k_new, v_new = _gqa_qkv_rope(cfg, params, x, positions)
+    k_pages, v_pages, k_scales, v_scales = _append_kv(
+        k_pages, v_pages, k_scales, v_scales, block_table, positions[:, 0],
+        k_new, v_new, page)
+    bt = block_table[:, :nact]
+    # the request's pages only, seen as tokens: no pool-sized relayout
+    k_all = lay.to_tokens(k_pages[bt])
+    v_all = lay.to_tokens(v_pages[bt])
     if k_scales is not None:
-        from ..kernels.paged_attention import (dequantize_kv_pages,
-                                               quantized_append)
-        k_pages, k_scales = quantized_append(k_pages, k_scales, block_table,
-                                             positions[:, 0], k_new)
-        v_pages, v_scales = quantized_append(v_pages, v_scales, block_table,
-                                             positions[:, 0], v_new)
-        bt = block_table[:, :nact]
-        k_all = dequantize_kv_pages(k_pages[bt], k_scales[bt], x.dtype)
-        v_all = dequantize_kv_pages(v_pages[bt], v_scales[bt], x.dtype)
-    else:
-        pid = jnp.take_along_axis(block_table, positions // page, axis=1)
-        off = positions % page
-        k_pages = k_pages.at[pid, off].set(k_new.astype(k_pages.dtype))
-        v_pages = v_pages.at[pid, off].set(v_new.astype(v_pages.dtype))
-        bt = block_table[:, :nact]
-        k_all = k_pages[bt]
-        v_all = v_pages[bt]
+        from ..kernels.paged_attention import dequantize_kv_pages
+        k_all = dequantize_kv_pages(k_all, k_scales[bt], x.dtype)
+        v_all = dequantize_kv_pages(v_all, v_scales[bt], x.dtype)
     k_all = k_all.reshape(B, nact * page, KH, D)
     v_all = v_all.reshape(B, nact * page, KH, D)
     qg = q.reshape(B, C, KH, G, D)

@@ -131,7 +131,7 @@ def norm_spec(cfg) -> Dict[str, ParamSpec]:
 
 def apply_norm(cfg, params: Dict, x: jax.Array) -> jax.Array:
     if cfg.norm == "rmsnorm":
-        return rmsnorm(x, params["scale"])
+        return rmsnorm(x, params["scale"], cfg.rms_norm_eps)
     if cfg.norm == "layernorm":
         return layernorm(x, params["scale"], params["bias"])
     if cfg.norm == "nonparam_ln":

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import BlockSpec, ModelConfig
+from ..kernels.paged_attention.layout import page_layout, write_tokens
 from .attention import gqa_decode_paged, gqa_prefill_paged
 from .common import apply_norm
 from .model import (_apply_block_decode, _cache_init_for_block, _embed,
@@ -121,7 +122,7 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, caches, cache_pos,
                       k_scales=None, v_scales=None, interpret: bool = False):
     """One autoregressive step over the paged pool.
 
-    tokens/cache_pos: (B,); k/v_pages: (P,page,KH,D); tables_pro:
+    tokens/cache_pos: (B,); k/v_pages: (P,R,L) stored pages; tables_pro:
     (n_paged_prologue, B, NP); tables_super: (repeats, n_paged_pattern, B, NP);
     k/v_scales: (P, KH) f32 when the pool is int8, else None.
     Returns (logits (B,V), new dense-fallback caches, k_pages, v_pages,
@@ -219,6 +220,32 @@ def prefill_chunk_paged(cfg: ModelConfig, params, tokens, start_pos,
 # Dense-prefill absorption (hybrid stacks)
 # ---------------------------------------------------------------------------
 
+def absorb_layer(k_pages, v_pages, k_scales, v_scales, pids, k, v,
+                 page: int):
+    """Write one layer's prompt K/V (S, KH, D), from position 0, into the
+    stored pages ``pids`` (host ids of its first ``ceil(S/page)`` pages).
+    Int8 pools quantize each destination page exactly once — no RMW drift
+    on this path.  Returns (k_pages, v_pages, k_scales, v_scales)."""
+    S, KH, D = k.shape
+    pids = jnp.asarray(pids)
+    if k_scales is None:
+        start = jnp.zeros((1,), jnp.int32)
+        return (write_tokens(k_pages, pids[None], start, k[None], page),
+                write_tokens(v_pages, pids[None], start, v[None], page),
+                None, None)
+    from ..kernels.paged_attention import quantize_kv_pages
+    lay = page_layout(page, KH, D)
+    pad = len(pids) * page - S
+
+    def quantized(x):
+        x = jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0), (0, 0)))
+        q, s = quantize_kv_pages(x.reshape(len(pids), page, KH, D))
+        return lay.to_rows(q), s
+    (kq, ks), (vq, vs) = quantized(k), quantized(v)
+    return (k_pages.at[pids].set(kq), v_pages.at[pids].set(vq),
+            k_scales.at[pids].set(ks), v_scales.at[pids].set(vs))
+
+
 def absorb_dense_prefill(cfg: ModelConfig, caches, k_pages, v_pages,
                          table, slot: int, seq_len: int, page: int, *,
                          k_scales=None, v_scales=None):
@@ -234,32 +261,14 @@ def absorb_dense_prefill(cfg: ModelConfig, caches, k_pages, v_pages,
     each destination page exactly once — no RMW drift on this path.
     Returns (caches', k_pages, v_pages, k_scales, v_scales).
     """
-    import numpy as np
-
     n_pro, n_pp = paged_layer_counts(cfg)
-    pos = np.arange(seq_len)
-    blk, off = pos // page, jnp.asarray(pos % page)
     nblk = -(-seq_len // page)
 
     def scatter(layer_idx, k, v):
         nonlocal k_pages, v_pages, k_scales, v_scales
-        if k_scales is not None:
-            from ..kernels.paged_attention import quantize_kv_pages
-            pids = jnp.asarray(table[layer_idx, slot, :nblk])
-            pad = nblk * page - seq_len
-            KH, D = k.shape[-2:]
-            kb = jnp.pad(k.astype(jnp.float32), ((0, pad), (0, 0), (0, 0)))
-            vb = jnp.pad(v.astype(jnp.float32), ((0, pad), (0, 0), (0, 0)))
-            kq, ks = quantize_kv_pages(kb.reshape(nblk, page, KH, D))
-            vq, vs = quantize_kv_pages(vb.reshape(nblk, page, KH, D))
-            k_pages = k_pages.at[pids].set(kq)
-            v_pages = v_pages.at[pids].set(vq)
-            k_scales = k_scales.at[pids].set(ks)
-            v_scales = v_scales.at[pids].set(vs)
-            return
-        pids = jnp.asarray(table[layer_idx, slot, blk])
-        k_pages = k_pages.at[pids, off].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[pids, off].set(v.astype(v_pages.dtype))
+        k_pages, v_pages, k_scales, v_scales = absorb_layer(
+            k_pages, v_pages, k_scales, v_scales,
+            table[layer_idx, slot, :nblk], k, v, page)
 
     out: Dict[str, Any] = {}
     if cfg.prologue:

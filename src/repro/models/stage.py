@@ -39,7 +39,8 @@ from ..core.placement import LayerRange
 from .common import apply_norm
 from .model import (_apply_block, _apply_block_decode, _cache_init_for_block,
                     _embed, _logits, fill_prefill_cache)
-from .paged import _block_decode_paged, _block_prefill_paged, is_paged_block
+from .paged import (_block_decode_paged, _block_prefill_paged, absorb_layer,
+                    is_paged_block)
 
 
 def _dtype(cfg: ModelConfig):
@@ -277,10 +278,6 @@ def stage_absorb_dense_prefill(cfg: ModelConfig, layers: LayerRange, caches,
     (n_local_paged, max_batch, NP) int32.  Int8 pools quantize each
     destination page exactly once.  Returns (caches', k_pages, v_pages,
     k_scales, v_scales)."""
-    import numpy as np
-
-    pos = np.arange(seq_len)
-    blk, off = pos // page, jnp.asarray(pos % page)
     nblk = -(-seq_len // page)
     out: List = []
     li = 0
@@ -288,27 +285,9 @@ def stage_absorb_dense_prefill(cfg: ModelConfig, layers: LayerRange, caches,
         if not is_paged_block(cfg, b):
             out.append(c)
             continue
-        if k_scales is not None:
-            from ..kernels.paged_attention import quantize_kv_pages
-            pids = jnp.asarray(table[li, slot, :nblk])
-            pad = nblk * page - seq_len
-            KH, D = c["k"].shape[-2:]
-            kb = jnp.pad(c["k"][0, :seq_len].astype(jnp.float32),
-                         ((0, pad), (0, 0), (0, 0)))
-            vb = jnp.pad(c["v"][0, :seq_len].astype(jnp.float32),
-                         ((0, pad), (0, 0), (0, 0)))
-            kq, ks = quantize_kv_pages(kb.reshape(nblk, page, KH, D))
-            vq, vs = quantize_kv_pages(vb.reshape(nblk, page, KH, D))
-            k_pages = k_pages.at[pids].set(kq)
-            v_pages = v_pages.at[pids].set(vq)
-            k_scales = k_scales.at[pids].set(ks)
-            v_scales = v_scales.at[pids].set(vs)
-        else:
-            pids = jnp.asarray(table[li, slot, blk])
-            k_pages = k_pages.at[pids, off].set(
-                c["k"][0, :seq_len].astype(k_pages.dtype))
-            v_pages = v_pages.at[pids, off].set(
-                c["v"][0, :seq_len].astype(v_pages.dtype))
+        k_pages, v_pages, k_scales, v_scales = absorb_layer(
+            k_pages, v_pages, k_scales, v_scales, table[li, slot, :nblk],
+            c["k"][0, :seq_len], c["v"][0, :seq_len], page)
         out.append({})
         li += 1
     return out, k_pages, v_pages, k_scales, v_scales

@@ -2,18 +2,26 @@
 
 One physical pool of ``num_pages`` K and V pages is shared by **all** of a
 node's paged attention layers — the paper's "pool of pages unified for all
-local layers".  A token occupies one row in one page *per paged layer*, so a
+local layers".  A token occupies one slot in one page *per paged layer*, so a
 logical sequence block costs ``num_paged_layers`` physical pages.  Page 0 is
 a scratch page: empty block-table entries point at it, so inactive batch
 slots write/read it harmlessly inside the jitted decode step.
 
-Pool-sizing math (see ``pages_for_vram``), per KV dtype:
+Pools are stored as ``kernels.paged_attention.layout.page_layout`` lays
+them out, ``(num_pages, rows, lanes)``: rows of ``lcm(head_dim, 128)``
+lanes, tokens major, each page whole ``(8, 128)`` tiles (OLMo-1B
+``(P, 256, 128)``, SmolLM-360M ``(P, 40, 128)``).  That module holds the
+rule the pool, its writes, the decode kernel and the sizing below share.
+
+Pool-sizing math (see ``pages_for_vram``), per KV dtype, in the bytes the
+chip stores (``PagePool.bytes_stored``):
 
     | quantity             | param dtype (bf16)        | kv_dtype="int8"     |
     |----------------------|---------------------------|---------------------|
     | kv element bytes     | 2                         | 1                   |
-    | page_bytes           | 2 * page * KH * D * 2     | 2 * page * KH * D   |
-    | scale_bytes / page   | 0                         | 2 * KH * 4 (f32)    |
+    | page_bytes           | 2 * rows * lanes * 2      | 2 * rows * lanes    |
+    |                      |  (= 2 * page * KH * D * 2)|  (= 2 * page*KH*D)  |
+    | scale_bytes / page   | 0                         | 2 * tiled(KH) * 4   |
     | num_pages            | pool_bytes // page_bytes  | pool_bytes //       |
     |                      |                           |  (page_bytes        |
     |                      |                           |   + scale_bytes)    |
@@ -26,14 +34,16 @@ plus the dtype-independent rows:
     | per-seq budget (NP)   | ceil(max_seq_len / page_size) blocks          |
     | min viable pool       | 1 + NP * n_paged_layers pages                 |
 
+The int8 scales are ``(num_pages, KH)`` float32, which XLA stores with the
+pages minor and ``KH`` rounded up to a tile's 8 rows (``tiled``: 5 kv heads
+hold 8 rows of scales; 1, 2, 4 and multiples of 8 are exact).
 With ``kv_dtype="int8"`` a page stores int8 elements plus one float32 absmax
 scale per (page, kv_head) for K and V each, so page cost drops from
-``4*page*KH*D`` bytes (K+V bf16) to ``2*page*KH*D + 8*KH`` — ≈2× the token
-capacity at fixed VRAM (the scale overhead is ``4 / page_size`` of a percent
-per element).  Unlike the dense engine's ``max_batch * max_len`` rectangle,
-capacity is shared: many short sequences or a few long ones fit the same
-pool, which is exactly the asymmetric-memory slack Helix's placement
-exploits on heterogeneous nodes.
+``4*page*KH*D`` bytes (K+V bf16) to ``2*page*KH*D + 8*tiled(KH)`` — ≈2× the
+token capacity at fixed VRAM.  Unlike the dense engine's
+``max_batch * max_len`` rectangle, capacity is shared: many short sequences
+or a few long ones fit the same pool, which is exactly the
+asymmetric-memory slack Helix's placement exploits on heterogeneous nodes.
 
 Allocation is on-demand (a block per ``page_size`` tokens, across layers),
 freed on request completion/preemption; admission control blocks new
@@ -51,7 +61,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..kernels.paged_attention.layout import PageLayout, page_layout, tiled
 from ..models.paged import num_paged_layers
+from .trace import TRACER
+
+
+def cfg_layout(cfg: ModelConfig, page: int) -> PageLayout:
+    return page_layout(page, cfg.num_kv_heads, cfg.resolved_head_dim)
 
 
 class PoolExhausted(RuntimeError):
@@ -61,14 +77,19 @@ class PoolExhausted(RuntimeError):
 class PagePool:
     """Shared K/V page pool + per-slot block tables and a free list.
 
-    Device arrays ``k``/``v`` have shape (num_pages, page_size, kv_heads,
-    head_dim) and are updated functionally by the jitted model steps (the
-    engine stores the returned arrays back).  With ``kv_dtype="int8"`` they
-    are int8 and ``k_scales``/``v_scales`` hold the (num_pages, kv_heads)
-    float32 per-page absmax scales (None otherwise).  The block table is a
-    host ``(num_paged_layers, max_batch, blocks_per_seq)`` int32 array; row
-    order is prologue layers first, then pattern positions repeat-major,
-    matching ``models.paged`` layer numbering.
+    Device arrays ``k``/``v`` are stored as ``page_layout`` lays them out,
+    ``(num_pages, rows, lanes)`` (``layout.to_tokens`` views a page as
+    ``(page_size, kv_heads, head_dim)``), and are updated functionally by
+    the jitted model steps (the engine stores the returned arrays back).
+    With ``kv_dtype="int8"`` they are int8 and ``k_scales``/``v_scales``
+    hold the (num_pages, kv_heads) float32 per-page absmax scales (None
+    otherwise).  ``bytes_stored`` and ``bytes_logical`` are what the chip
+    holds for the pool and what its tokens need; each pool adds them to the
+    tracer's ``kv_pool.bytes_stored`` / ``kv_pool.bytes_logical`` counters.
+    The block table is a host ``(num_paged_layers, max_batch,
+    blocks_per_seq)`` int32 array; row order is prologue layers first, then
+    pattern positions repeat-major, matching ``models.paged`` layer
+    numbering.
     """
 
     def __init__(self, cfg: ModelConfig, *, num_pages: int, page_size: int,
@@ -97,7 +118,8 @@ class PagePool:
                              f"got {kv_dtype!r}")
         self.kv_dtype = "int8" if kv_dtype == "int8" else "param"
         self.quantized = self.kv_dtype == "int8"
-        kh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+        self.layout = cfg_layout(cfg, page_size)
+        kh = cfg.num_kv_heads
         if self.quantized:
             dtype = jnp.int8
             self.k_scales = jnp.zeros((num_pages, kh), jnp.float32)
@@ -109,8 +131,16 @@ class PagePool:
             self.k_scales = None
             self.v_scales = None
         self.num_pages = num_pages
-        self.k = jnp.zeros((num_pages, page_size, kh, hd), dtype)
-        self.v = jnp.zeros((num_pages, page_size, kh, hd), dtype)
+        self.k = jnp.zeros(self.layout.shape(num_pages), dtype)
+        self.v = jnp.zeros(self.layout.shape(num_pages), dtype)
+        itemsize = jnp.dtype(dtype).itemsize
+        self.bytes_stored = num_pages * _page_bytes(self.layout, itemsize,
+                                                    self.quantized)
+        self.bytes_logical = num_pages * 2 * (
+            self.layout.logical_bytes(itemsize)
+            + (4 * kh if self.quantized else 0))
+        TRACER.count("kv_pool.bytes_stored", self.bytes_stored)
+        TRACER.count("kv_pool.bytes_logical", self.bytes_logical)
         # page 0 reserved as scratch; the free list is a preallocated stack
         # whose live region is _free[:_free_top] (top of stack at the end,
         # matching the old list.pop() order: page 1 first, then 2, ...)
@@ -239,14 +269,20 @@ def full_rectangle_pages(cfg: ModelConfig, *, max_batch: int, max_len: int,
     return 1 + blocks * layers * max_batch
 
 
+def _page_bytes(layout: PageLayout, itemsize: int, quantized: bool) -> int:
+    """Bytes the chip stores for one page of K and V (and its scales)."""
+    scales = 2 * tiled(layout.kv_heads) * 4 if quantized else 0
+    return 2 * layout.page_bytes(itemsize) + scales
+
+
 def page_bytes(cfg: ModelConfig, page_size: int,
-               kv_dtype: Optional[str] = None) -> float:
-    """Bytes one pool page costs (K + V + int8 scale rows, if any)."""
-    kh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    if kv_dtype == "int8":
-        return 2 * page_size * kh * hd * 1 + 2 * kh * 4
-    elt = {"bfloat16": 2, "float32": 4}[cfg.param_dtype]
-    return 2 * page_size * kh * hd * elt
+               kv_dtype: Optional[str] = None) -> int:
+    """Bytes the chip stores for one pool page (K + V + int8 scale rows,
+    if any), as ``page_layout`` lays it out."""
+    quantized = kv_dtype == "int8"
+    itemsize = 1 if quantized else \
+        {"bfloat16": 2, "float32": 4}[cfg.param_dtype]
+    return _page_bytes(cfg_layout(cfg, page_size), itemsize, quantized)
 
 
 def pages_for_vram(cfg: ModelConfig, vram_bytes: float, *, page_size: int,
@@ -259,8 +295,8 @@ def pages_for_vram(cfg: ModelConfig, vram_bytes: float, *, page_size: int,
     whole model); ``max_pages`` caps the result (useful for smoke models
     whose tiny pages would otherwise number in the millions).
     ``kv_dtype="int8"`` halves the per-page cost (1-byte elements plus
-    ``2 * kv_heads * 4`` scale bytes per page) — ≈2x the pages from the same
-    VRAM."""
+    ``2 * tiled(kv_heads) * 4`` scale bytes per page) — ≈2x the pages from
+    the same VRAM.  Pages cost what the chip stores (``page_bytes``)."""
     elt = {"bfloat16": 2, "float32": 4}[cfg.param_dtype]
     pb = page_bytes(cfg, page_size, kv_dtype)
     layers = layers_on_node if layers_on_node is not None else cfg.num_layers

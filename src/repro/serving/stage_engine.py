@@ -42,6 +42,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..core.placement import LayerRange
+from ..kernels.paged_attention import streamed_pages_per_step
 from ..models.paged import all_blocks_paged, is_paged_block
 from ..models.stage import (stage_absorb_dense_prefill, stage_blocks,
                             stage_cache_init, stage_cache_init_paged,
@@ -192,11 +193,17 @@ class _StageEngineBase:
     # -- decode orchestration ---------------------------------------------
     def _decode_span(self, items: List[DecodeItem]):
         """The ``helix.engine.decode`` span of one step: its call index and
-        rows, and (in memory only) each row's context after the step."""
+        rows, the K/V pages its kernel streams (paged engines), and (in
+        memory only) each row's context after the step."""
         i = self.decode_calls
         self.decode_calls += 1
+        ctx = tuple(it.pos + 1 for it in items)
         return TRACER.span("helix.engine.decode", call=i, rows=len(items),
-                           ctx=tuple(it.pos + 1 for it in items))
+                           ctx=ctx, **self._streamed(ctx))
+
+    def _streamed(self, ctx) -> Dict[str, int]:
+        """Span attrs for the K/V a step over contexts ``ctx`` streams."""
+        return {}
 
     @staticmethod
     def _fetch(*outs):
@@ -448,6 +455,10 @@ class PagedStageEngine(_StageEngineBase):
         self._spec_snaps: Dict[int, Dict[int, dict]] = {}
 
     # -- pool ------------------------------------------------------------
+    def _streamed(self, ctx) -> Dict[str, int]:
+        return {"pages": self.n_paged
+                * streamed_pages_per_step(ctx, self.pool.page)}
+
     def ensure(self, slot: int, tokens: int) -> bool:
         return self.pool.ensure(slot, tokens)
 

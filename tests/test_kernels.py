@@ -18,9 +18,18 @@ from repro.kernels.paged_attention import (dense_to_pages, paged_attention,
                                            paged_attention_ref,
                                            quantize_kv_pages,
                                            streamed_pages_per_step)
+from repro.kernels.paged_attention.layout import (page_layout, pool_layout,
+                                                  write_tokens)
 
 TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
        jnp.bfloat16: dict(rtol=3e-2, atol=3e-2)}
+
+
+def _quantized(pages, page, KH, D):
+    """Int8 stored pages and their scales, quantized from the token view."""
+    lay = page_layout(page, KH, D)
+    q, scales = quantize_kv_pages(lay.to_tokens(pages))
+    return lay.to_rows(q), scales
 
 
 def _mk_qkv(key, B, H, KH, Sq, Sk, D, dtype):
@@ -108,9 +117,10 @@ def test_paged_attention_matches_ref(shape, dtype):
     v = jax.random.normal(k3, (B, S, KH, D), jnp.float32).astype(dtype)
     lengths = jax.random.randint(k4, (B,), 1, S + 1)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    out = paged_attention(q, k_pages, v_pages, tables, lengths,
+    out = paged_attention(q, k_pages, v_pages, tables, lengths, page=page,
                           interpret=True)
-    ref = paged_attention_ref(q, k_pages, v_pages, tables, lengths)
+    ref = paged_attention_ref(q, k_pages, v_pages, tables, lengths,
+                              page=page)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOL[dtype])
 
@@ -126,14 +136,14 @@ def test_paged_attention_scrambled_pages():
     v = jax.random.normal(k3, (B, S, KH, D))
     lengths = jnp.array([200, 130], jnp.int32)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    out1 = paged_attention(q, k_pages, v_pages, tables, lengths,
+    out1 = paged_attention(q, k_pages, v_pages, tables, lengths, page=page,
                            interpret=True)
     # scramble physical page order with a permutation
     P = k_pages.shape[0]
     perm = jax.random.permutation(jax.random.key(9), P)
     inv = jnp.argsort(perm)
     out2 = paged_attention(q, k_pages[perm], v_pages[perm], inv[tables],
-                           lengths, interpret=True)
+                           lengths, page=page, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-5, atol=1e-5)
 
@@ -158,9 +168,10 @@ def test_paged_attention_ragged_property(b, page, nblk, seed):
     v = jax.random.normal(k3, (b, S, KH, D))
     lengths = jax.random.randint(k4, (b,), 1, S + 1)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    out = paged_attention(q, k_pages, v_pages, tables, lengths,
+    out = paged_attention(q, k_pages, v_pages, tables, lengths, page=page,
                           interpret=True)
-    ref = paged_attention_ref(q, k_pages, v_pages, tables, lengths)
+    ref = paged_attention_ref(q, k_pages, v_pages, tables, lengths,
+                              page=page)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
     # live-page traffic accounting: never more than the dense grid
@@ -180,12 +191,12 @@ def test_paged_attention_int8_matches_ref(shape):
     v = jax.random.normal(k3, (B, S, KH, D))
     lengths = jax.random.randint(k4, (B,), 1, S + 1)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    kq, ks = quantize_kv_pages(k_pages)
-    vq, vs = quantize_kv_pages(v_pages)
-    out = paged_attention(q, kq, vq, tables, lengths,
+    kq, ks = _quantized(k_pages, page, KH, D)
+    vq, vs = _quantized(v_pages, page, KH, D)
+    out = paged_attention(q, kq, vq, tables, lengths, page=page,
                           k_scales=ks, v_scales=vs, interpret=True)
     ref = paged_attention_ref(q, kq, vq, tables, lengths,
-                              k_scales=ks, v_scales=vs)
+                              k_scales=ks, v_scales=vs, page=page)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
@@ -201,7 +212,7 @@ def test_paged_attention_matches_dense_decode():
     v = jax.random.normal(k3, (B, S, KH, D))
     lengths = jnp.array([100, 64], jnp.int32)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    out = paged_attention(q, k_pages, v_pages, tables, lengths,
+    out = paged_attention(q, k_pages, v_pages, tables, lengths, page=page,
                           interpret=True)
     # dense reference
     G = H // KH
@@ -224,6 +235,7 @@ EDGE_LAYOUTS = {
     "olmo_mha": (16, 16, 128),
     "gqa": (8, 2, 128),
     "mqa": (4, 1, 64),
+    "smollm_gqa": (15, 5, 64),        # G = 3 over 5 kv heads, 2 records a row
 }
 EDGE_PAGE, EDGE_NP = 16, 12
 
@@ -246,16 +258,19 @@ def _edge_inputs(layout, dtype, seed):
     v = jax.random.normal(k3, (P, page, KH, D), jnp.float32)
     tables = jax.random.permutation(k4, P)[:B * NP].reshape(B, NP)
     lengths = jnp.asarray(lens, jnp.int32)
+    lay = page_layout(page, KH, D)
     if dtype == "int8":
         (k, ks), (v, vs) = quantize_kv_pages(k), quantize_kv_pages(v)
-        return q, k, v, tables.astype(jnp.int32), lengths, ks, vs
-    return (q.astype(dtype), k.astype(dtype), v.astype(dtype),
-            tables.astype(jnp.int32), lengths, None, None)
+        return (q, lay.to_rows(k), lay.to_rows(v), tables.astype(jnp.int32),
+                lengths, ks, vs)
+    return (q.astype(dtype), lay.to_rows(k).astype(dtype),
+            lay.to_rows(v).astype(dtype), tables.astype(jnp.int32), lengths,
+            None, None)
 
 
 def _check_edge(out, q, k, v, tables, lengths, ks, vs, dtype):
     ref = paged_attention_ref(q, k, v, tables, lengths,
-                              k_scales=ks, v_scales=vs)
+                              k_scales=ks, v_scales=vs, page=EDGE_PAGE)
     out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
     live = np.asarray(lengths) > 0
     tol = TOL[jnp.bfloat16] if dtype == jnp.bfloat16 else TOL[jnp.float32]
@@ -270,8 +285,8 @@ def test_paged_attention_edge_lengths(layout, dtype):
     """Lengths at every page and block boundary, through the same kernel
     for MHA, GQA and MQA heads in float32, bfloat16 and int8 pages."""
     q, k, v, tables, lengths, ks, vs = _edge_inputs(layout, dtype, 5)
-    out = paged_attention(q, k, v, tables, lengths, k_scales=ks,
-                          v_scales=vs, interpret=True)
+    out = paged_attention(q, k, v, tables, lengths, page=EDGE_PAGE,
+                          k_scales=ks, v_scales=vs, interpret=True)
     _check_edge(out, q, k, v, tables, lengths, ks, vs, dtype)
 
 
@@ -291,10 +306,44 @@ def test_paged_attention_never_reads_dead_pages(dtype):
         pks = jnp.where(dead[:, None], jnp.nan, ks)
         pvs = jnp.where(dead[:, None], jnp.nan, vs)
     else:
-        pk = jnp.where(dead[:, None, None, None], jnp.nan, k)
-        pv = jnp.where(dead[:, None, None, None], jnp.nan, v)
+        pk = jnp.where(dead[:, None, None], jnp.nan, k)
+        pv = jnp.where(dead[:, None, None], jnp.nan, v)
         pks = pvs = None
-    out = paged_attention(q, pk, pv, tables, lengths, k_scales=pks,
-                          v_scales=pvs, interpret=True)
+    out = paged_attention(q, pk, pv, tables, lengths, page=EDGE_PAGE,
+                          k_scales=pks, v_scales=pvs, interpret=True)
     assert np.all(np.isfinite(np.asarray(out, np.float32)))
     _check_edge(out, q, k, v, tables, lengths, ks, vs, dtype)
+
+
+# --- stored pool layout: writes -------------------------------------------
+
+@pytest.mark.parametrize("C", [1, 3, 16, 17, 40])
+@pytest.mark.parametrize("heads", [(5, 64, 16), (16, 128, 16), (2, 16, 8),
+                                   (5, 64, 32)],
+                         ids=["smollm", "olmo", "small", "smollm_page32"])
+def test_write_tokens_matches_token_view(heads, C):
+    """A write of C tokens at any start into the stored pool equals the same
+    write into the token view: runs of one token (OLMo-1B's heads) and runs
+    of a whole page whose tokens straddle rows (SmolLM-360M's), from
+    random starts, with writes that span several runs and pages.  Every
+    page but the scratch page 0 is compared, so a token the write should
+    have kept is caught."""
+    KH, D, page = heads
+    lay = page_layout(page, KH, D)
+    B, NP = 3, 8
+    P = 1 + B * NP
+    rng = np.random.default_rng(C)
+    pool = rng.standard_normal(lay.shape(P)).astype(np.float32)
+    table = 1 + rng.permutation(P - 1)[:B * NP].reshape(B, NP)
+    start = rng.integers(0, NP * page - C + 1, B)
+    new = rng.standard_normal((B, C, KH, D)).astype(np.float32)
+    got = write_tokens(jnp.asarray(pool), jnp.asarray(table, jnp.int32),
+                       jnp.asarray(start, jnp.int32), jnp.asarray(new), page)
+    want = pool.reshape(P, page, KH, D).copy()
+    for b in range(B):
+        for c in range(C):
+            pos = start[b] + c
+            want[table[b, pos // page], pos % page] = new[b, c]
+    assert pool_layout(got.shape, D, kv_heads=KH) == lay
+    np.testing.assert_array_equal(np.asarray(got)[1:],
+                                  lay.to_rows(want)[1:])

@@ -32,6 +32,7 @@ from repro.kernels.paged_attention import (dense_to_pages,
                                            streamed_pages_per_step)
 from repro.serving import (EngineConfig, PagedEngine, PagePool, Request,
                            page_bytes, pages_for_vram)
+from repro.kernels.paged_attention.layout import page_layout
 
 from harness import (EC, assert_pools_drained, assert_serves_like_reference,
                      make_disagg_plan, make_plan, random_prompts,
@@ -40,13 +41,22 @@ from harness import (EC, assert_pools_drained, assert_serves_like_reference,
 
 # --- kernel: int8 parity -----------------------------------------------------
 
+def _quantized(pages, page, KH, D):
+    """Int8 stored pages and their scales, quantized from the token view."""
+    lay = page_layout(page, KH, D)
+    q, scales = quantize_kv_pages(lay.to_tokens(pages))
+    return lay.to_rows(q), scales
+
+
 def _numpy_quantized_oracle(q, kq, ks, vq, vs, tables, lengths, page):
     """Dequantize with numpy, gather logical KV, exact softmax attention."""
     q, kq, ks, vq, vs = map(np.asarray, (q, kq, ks, vq, vs))
     tables, lengths = np.asarray(tables), np.asarray(lengths)
     B, H, D = q.shape
-    KH = kq.shape[2]
+    KH = ks.shape[1]
     G = H // KH
+    kq = kq.reshape(kq.shape[0], page, KH, D)
+    vq = vq.reshape(vq.shape[0], page, KH, D)
     k = kq.astype(np.float32) * ks[:, None, :, None]
     v = vq.astype(np.float32) * vs[:, None, :, None]
     out = np.zeros((B, H, D), np.float32)
@@ -72,9 +82,9 @@ def test_int8_kernel_matches_numpy_oracle():
     v = jax.random.normal(k3, (B, S, KH, D))
     lengths = jax.random.randint(k4, (B,), 1, S + 1)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    kq, ks = quantize_kv_pages(k_pages)
-    vq, vs = quantize_kv_pages(v_pages)
-    out = paged_attention(q, kq, vq, tables, lengths,
+    kq, ks = _quantized(k_pages, page, KH, D)
+    vq, vs = _quantized(v_pages, page, KH, D)
+    out = paged_attention(q, kq, vq, tables, lengths, page=page,
                           k_scales=ks, v_scales=vs, interpret=True)
     oracle = _numpy_quantized_oracle(q, kq, ks, vq, vs, tables, lengths, page)
     # kernel vs same-quantization oracle: only fp accumulation differs
@@ -92,11 +102,11 @@ def test_int8_kernel_bounded_error_vs_exact():
     v = jax.random.normal(k3, (B, S, KH, D))
     lengths = jnp.array([100, 64], jnp.int32)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    exact = paged_attention(q, k_pages, v_pages, tables, lengths,
+    exact = paged_attention(q, k_pages, v_pages, tables, lengths, page=page,
                             interpret=True)
-    kq, ks = quantize_kv_pages(k_pages)
-    vq, vs = quantize_kv_pages(v_pages)
-    quant = paged_attention(q, kq, vq, tables, lengths,
+    kq, ks = _quantized(k_pages, page, KH, D)
+    vq, vs = _quantized(v_pages, page, KH, D)
+    quant = paged_attention(q, kq, vq, tables, lengths, page=page,
                             k_scales=ks, v_scales=vs, interpret=True)
     err = np.abs(np.asarray(quant) - np.asarray(exact)).max()
     assert err < 0.08, f"int8 KV error {err:.4f} vs exact attention"
@@ -134,7 +144,7 @@ def test_variable_context_ragged_exact(page, lens):
     v = jax.random.normal(k3, (B, S, KH, D))
     lengths = jnp.asarray(lens, jnp.int32)
     k_pages, v_pages, tables = dense_to_pages(k, v, lengths, page)
-    out = paged_attention(q, k_pages, v_pages, tables, lengths,
+    out = paged_attention(q, k_pages, v_pages, tables, lengths, page=page,
                           interpret=True)
     # exact dense oracle over the logical (unpadded) KV
     G = H // KH
@@ -172,7 +182,8 @@ def test_quantized_append_matches_numpy_requantize():
     rng = np.random.RandomState(0)
     page, NP, KH, D, B = 8, 6, 2, 16, 2
     P = 1 + B * NP
-    pages = jnp.zeros((P, page, KH, D), jnp.int8)
+    lay = page_layout(page, KH, D)
+    pages = jnp.zeros(lay.shape(P), jnp.int8)
     scales = jnp.zeros((P, KH), jnp.float32)
     table = jnp.asarray(
         np.arange(1, P).reshape(B, NP).astype(np.int32))
@@ -187,7 +198,7 @@ def test_quantized_append_matches_numpy_requantize():
             hist[b, start[b]:start[b] + C] = rows[b]
         start += C
         # oracle: re-quantize every page from the exact history
-        back = np.asarray(dequantize_kv_pages(pages, scales))
+        back = np.asarray(dequantize_kv_pages(lay.to_tokens(pages), scales))
         for b in range(B):
             nb = -(-int(start[b]) // page)
             for j in range(nb):
@@ -201,7 +212,7 @@ def test_quantized_append_matches_numpy_requantize():
     # inflating a freshly allocated page's absmax)
     b, L = 0, int(start[0])
     nb = -(-L // page)
-    tail = np.asarray(dequantize_kv_pages(pages, scales))[
+    tail = np.asarray(dequantize_kv_pages(lay.to_tokens(pages), scales))[
         int(np.asarray(table)[b, nb - 1])].reshape(page, KH, D)
     w = L - (nb - 1) * page
     assert (tail[w:] == 0).all()
