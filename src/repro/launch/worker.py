@@ -231,9 +231,10 @@ class StageWorker:
                 return None
             return out
         if method == "decode_stage":
-            # wire items are 6-tuples since speculative decoding: a trailing
-            # ``tokens`` vector marks a multi-token verify pass; both ``h``
-            # and ``tokens`` may arrive as StagedRefs pushed by a peer
+            # wire items are 7-tuples: a ``tokens`` vector marks a
+            # multi-token verify pass (both ``h`` and ``tokens`` may arrive
+            # as StagedRefs pushed by a peer), then ``want_logits``; shorter
+            # items are read with the defaults they were written under
             items = []
             for w in args[0]:
                 s, p, e, t, h = w[:5]
@@ -241,7 +242,8 @@ class StageWorker:
                     else None
                 items.append(DecodeItem(
                     slot=s, pos=p, entry=e, token=t, h=self._resolve(h),
-                    tokens=None if tk is None else [int(x) for x in tk]))
+                    tokens=None if tk is None else [int(x) for x in tk],
+                    want_logits=bool(w[6]) if len(w) > 6 else True))
             fwds = args[1] if len(args) > 1 else None
             outs = eng.decode_stage(items)
             reply = []
@@ -249,9 +251,9 @@ class StageWorker:
                 f = fwds[i] if fwds else None
                 if f is not None:
                     pushes.append((f[0], f[1], o.h))
-                    reply.append((None, o.logits))
+                    reply.append((None, o.logits, o.token))
                 else:
-                    reply.append((o.h, o.logits))
+                    reply.append((o.h, o.logits, o.token))
             return reply
         if method == "export_kv":
             slot, tokens, layers = args[:3]

@@ -1312,14 +1312,15 @@ class ClusterRuntime:
 
         catch = [tok_at(r) for r in range(job.draft_pos, p + 1)]
         out = eng.decode_stage([DecodeItem(slot=slot, pos=job.draft_pos,
-                                           entry=0, tokens=catch)])[0]
-        logits = np.asarray(out.logits)
-        cur = int(np.argmax(logits[-1] if logits.ndim == 2 else logits))
+                                           entry=0, tokens=catch,
+                                           want_logits=False)])[0]
+        cur = int(np.atleast_1d(out.token)[-1])
         drafts = [cur]
         for s in range(1, gamma):
             out = eng.decode_stage([DecodeItem(slot=slot, pos=p + s,
-                                               entry=0, token=cur)])[0]
-            cur = int(np.argmax(out.logits))
+                                               entry=0, token=cur,
+                                               want_logits=False)])[0]
+            cur = out.token
             drafts.append(cur)
         job.draft_pos = p + 1        # rows 0..p are now confirmed-consumed
         return drafts
@@ -1452,7 +1453,9 @@ class ClusterRuntime:
                                 entry=w["job"].pipe.stages[w["si"]]
                                 .layers.start,
                                 token=w["tok"], h=w["h"],
-                                tokens=w.get("toks")) for w in batch]
+                                tokens=w.get("toks"),
+                                want_logits=w["job"].req.temperature > 0)
+                     for w in batch]
             fwds = None
             if getattr(eng, "forward_capable", False) and \
                     getattr(self.transport, "direct_links", False):
@@ -1477,27 +1480,26 @@ class ClusterRuntime:
 
     def _route_outputs(self, node: str, eng, batch: List[dict],
                        outs) -> None:
-        """After a decode call: sample at the final stage and send each
-        token to the coordinator (launching the next pass), or send the
-        activations on to the next stage."""
+        """After a decode call: take the greedy token at the final stage
+        (or sample one, at temperature > 0) and send it to the coordinator
+        (launching the next pass), or send the activations on to the next
+        stage."""
         for w, out in zip(batch, outs):
             job, si, epoch, j = w["job"], w["si"], w["epoch"], w["j"]
             if si == len(job.pipe.stages) - 1:
                 if w.get("spec"):
                     # verify pass: no sampling, no node-side launch —
-                    # the greedy argmax vector (one per verified
-                    # position; identical to what sample() computes at
-                    # temperature <= 0) returns to the coordinator,
-                    # which owns acceptance and rollback
-                    greedy = np.asarray(
-                        np.argmax(np.asarray(out.logits), axis=-1),
-                        np.int32).reshape(-1)
+                    # the device's greedy ids (one per verified
+                    # position) return to the coordinator, which owns
+                    # acceptance and rollback
+                    greedy = np.asarray(out.token, np.int32).reshape(-1)
                     self._send(node, COORDINATOR, (j, greedy),
                                len(greedy) * self.profile.token_bytes,
                                lambda p, jb=job, e=epoch:
                                self._on_spec_result(jb, e, p[0], p[1]))
                     continue
-                tok = eng.sample(out.logits, job.req.temperature)
+                tok = out.token if job.req.temperature <= 0 else \
+                    eng.sample(out.logits, job.req.temperature)
                 self._send(node, COORDINATOR, (j, tok),
                            self.profile.token_bytes,
                            lambda p, jb=job, e=epoch:

@@ -15,7 +15,13 @@ KV for one node's assigned ``LayerRange`` and exposes a stage-level API the
                                    stage-work is resident this iteration —
                                    per-node continuous batching; items may
                                    mix requests entering at different layers
-  sample(logits, temperature)      final-stage token sampling
+  sample(logits, temperature)      final-stage sampling at temperature > 0
+
+At the final stage each decode step also takes the greedy token of every
+row on the device (``DecodeOut.token``) and copies back only those ids;
+the full-vocabulary logits are copied only when some item of the batch
+asked for them (``DecodeItem.want_logits``).  Off the final stage a step
+copies back the activations the next stage reads, and nothing else.
 
 Slot mechanics: caches (and the paged pool's block table) carry
 ``max_batch + 1`` rows; the extra row is scratch — decode batches are padded
@@ -35,9 +41,10 @@ before each step.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
@@ -66,7 +73,13 @@ class DecodeItem:
     (n, 1, d).  The engines run multi-token items as ``n`` position-ordered
     sub-steps, so the KV write history (and on int8 pools the per-page
     requantization history) is byte-identical to ``n`` ordinary decode
-    steps — acceptance rate can only change speed, never bytes."""
+    steps — acceptance rate can only change speed, never bytes.
+
+    ``want_logits`` says the caller reads this row's full-vocabulary
+    logits.  The runtime sets it exactly for rows that sample at
+    ``temperature > 0``; a greedy row reads ``DecodeOut.token`` alone, and
+    a step whose rows are all greedy copies no logits off the device.  It
+    defaults to True so a caller that checks logits gets them."""
 
     slot: int
     pos: int                      # absolute position of the FIRST token
@@ -74,6 +87,7 @@ class DecodeItem:
     token: int = 0                # consumed only when entry == 0
     h: Optional[np.ndarray] = None  # (n, 1, d) incoming activations
     tokens: Optional[Sequence[int]] = None  # verify pass (entry == 0 only)
+    want_logits: bool = True      # final stage: return this row's logits
 
     @property
     def n(self) -> int:
@@ -90,13 +104,26 @@ class DecodeItem:
             slot=self.slot, pos=self.pos + s, entry=self.entry,
             token=int(self.tokens[s]) if self.tokens is not None
             else self.token,
-            h=None if self.h is None else np.asarray(self.h[s:s + 1]))
+            h=None if self.h is None else np.asarray(self.h[s:s + 1]),
+            want_logits=self.want_logits)
 
 
 @dataclasses.dataclass
 class DecodeOut:
+    """One item's decode result.  Off the final stage: ``h``.  At the
+    final stage: ``token``, the device's argmax of the row's logits (the
+    first maximum, as ``np.argmax`` picks it), and ``logits`` when the item
+    asked for them."""
     h: Optional[np.ndarray]       # (n, 1, d) outgoing activations
     logits: Optional[np.ndarray]  # (V,) — or (n, V) for a verify pass
+    token: Optional[Union[int, np.ndarray]] = None  # int — or (n,) int32
+
+
+def _greedy(logits):
+    """Each row's greedy token, inside the step's program (None off the
+    final stage)."""
+    return None if logits is None else \
+        jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 class _StageEngineBase:
@@ -208,16 +235,29 @@ class _StageEngineBase:
     @staticmethod
     def _fetch(*outs):
         """Wait for a step's outputs on the device, then copy them to the
-        host (``None`` stays ``None``)."""
+        host (``None`` stays ``None``); the fetch span's ``bytes`` is what
+        the copies move."""
         with TRACER.span("helix.engine.wait"):
             jax.block_until_ready(outs)
-        with TRACER.span("helix.engine.fetch"):
+        nbytes = sum(o.nbytes for o in outs if o is not None)
+        with TRACER.span("helix.engine.fetch", bytes=nbytes):
             return tuple(None if o is None else np.asarray(o) for o in outs)
 
+    def _fetch_decode(self, items: List[DecodeItem], h, logits, ids):
+        """Copy back what a decode step's callers read, as (h, logits,
+        ids): ``h`` off the final stage; at the final stage the ids, and
+        the (B, V) logits only when some item asked for them."""
+        if not self.is_last:
+            return self._fetch(h) + (None, None)
+        if not any(it.want_logits for it in items):
+            return (None, None) + self._fetch(ids)
+        TRACER.count("decode.logits_rows", int(logits.shape[0]))
+        return (None,) + self._fetch(logits, ids)
+
     def _decode_step(self, items: List[DecodeItem]):
-        """One batched single-token decode step.  Returns (h, logits) as
-        numpy arrays of shape (len(items), 1, d) and (len(items), V) (or
-        None off the final stage)."""
+        """One batched single-token decode step.  Returns (h, logits, ids)
+        as numpy arrays of shape (B, 1, d), (B, V) and (B,), rows in item
+        order, or None where ``_fetch_decode`` copies nothing."""
         raise NotImplementedError
 
     def _spec_begin(self, it: DecodeItem) -> None:
@@ -245,34 +285,42 @@ class _StageEngineBase:
             # normalize length-1 ``tokens`` items into plain token items
             items = [it if it.tokens is None else it.substep(0)
                      for it in items]
-            h, l = self._decode_step(items)
-            return [DecodeOut(h=h[i:i + 1],
-                              logits=l[i] if l is not None else None)
-                    for i in range(len(items))]
+            h, l, t = self._decode_step(items)
+            return [DecodeOut(
+                h=None if h is None else h[i:i + 1],
+                logits=l[i] if l is not None and it.want_logits else None,
+                token=None if t is None else int(t[i]))
+                for i, it in enumerate(items)]
         for it in items:
             if it.n > 1:
                 self._spec_begin(it)
         hs: List[List[np.ndarray]] = [[] for _ in items]
         ls: List[List[np.ndarray]] = [[] for _ in items]
+        ts: List[List[int]] = [[] for _ in items]
         for s in range(n):
             sel = [i for i, it in enumerate(items) if s < it.n]
             sub = [items[i].substep(s) for i in sel]
-            h, l = self._decode_step(sub)
+            h, l, t = self._decode_step(sub)
             for k, i in enumerate(sel):
-                hs[i].append(h[k:k + 1])
-                if l is not None:
+                if h is not None:
+                    hs[i].append(h[k:k + 1])
+                if l is not None and items[i].want_logits:
                     ls[i].append(l[k])
+                if t is not None:
+                    ts[i].append(int(t[k]))
                 if items[i].n > 1:
                     self._snap_substep(items[i], s)
         outs = []
         for i, it in enumerate(items):
-            if it.n == 1:   # keep single-token output shapes: (1,1,d) / (V,)
-                outs.append(DecodeOut(h=hs[i][0],
-                                      logits=ls[i][0] if ls[i] else None))
+            if it.n == 1:   # single-token shapes: (1,1,d) / (V,) / int
+                outs.append(DecodeOut(h=hs[i][0] if hs[i] else None,
+                                      logits=ls[i][0] if ls[i] else None,
+                                      token=ts[i][0] if ts[i] else None))
             else:
                 outs.append(DecodeOut(
-                    h=np.concatenate(hs[i], axis=0),
-                    logits=np.stack(ls[i], axis=0) if ls[i] else None))
+                    h=np.concatenate(hs[i], axis=0) if hs[i] else None,
+                    logits=np.stack(ls[i], axis=0) if ls[i] else None,
+                    token=np.asarray(ts[i], np.int32) if ts[i] else None))
         return outs
 
 
@@ -310,7 +358,7 @@ class StageEngine(_StageEngineBase):
                                          cg, pos)
             new = jax.tree.map(lambda full, n: full.at[idx].set(n),
                                caches, nc)
-            return h, logits, new
+            return h, logits, _greedy(logits), new
 
         self._decode = jax.jit(decode_fn)
         self._active_tokens = np.zeros((ec.max_batch,), np.int64)
@@ -336,11 +384,11 @@ class StageEngine(_StageEngineBase):
             with TRACER.span("helix.engine.inputs"):
                 idx, tok, pos, entry, h_in = self._assemble(items)
             with TRACER.span("helix.engine.launch"):
-                h, logits, self.caches = self._decode(
+                h, logits, ids, self.caches = self._decode(
                     self.sparams, self.caches, tok, h_in, entry, pos, idx)
             for it in items:
                 self._active_tokens[it.slot] = it.pos + 1
-            return self._fetch(h, logits)
+            return self._fetch_decode(items, h, logits, ids)
 
     def rollback(self, slot: int, tokens: int) -> None:
         """Dense caches are positional and attention masks rows >= pos, so
@@ -387,6 +435,25 @@ class StageEngine(_StageEngineBase):
                     c, one))
         self.caches = new
         self._active_tokens[slot] = tokens
+
+
+def paged_decode_step(cfg: ModelConfig, layers: LayerRange,
+                      interpret: bool):
+    """The paged engine's decode step over ``layers``, to be jitted: the
+    batch's cache rows and block-table columns gathered by ``idx``, the
+    slice's layers, and at the final stage the logits and their greedy
+    ids, in one program.  Returns ``(h, logits, ids, caches, k_pages,
+    v_pages, k_scales, v_scales)``."""
+    def step(sp, caches, tok, h_in, entry, pos, idx, kp, vp, ks, vs,
+             tables):
+        cg = jax.tree.map(lambda c: c[idx], caches)
+        tb = tables[:, idx]
+        h, logits, nc, kp, vp, ks, vs = stage_decode_paged(
+            cfg, sp, layers, tok, h_in, entry, cg, pos, kp, vp, tb,
+            k_scales=ks, v_scales=vs, interpret=interpret)
+        new = jax.tree.map(lambda full, n: full.at[idx].set(n), caches, nc)
+        return h, logits, _greedy(logits), new, kp, vp, ks, vs
+    return step
 
 
 class PagedStageEngine(_StageEngineBase):
@@ -438,19 +505,9 @@ class PagedStageEngine(_StageEngineBase):
                                                    max_len=ec.max_len),
                 static_argnums=(2,))
 
-        def decode_fn(sp, caches, tok, h_in, entry, pos, idx, kp, vp, ks, vs,
-                      tables):
-            cg = jax.tree.map(lambda c: c[idx], caches)
-            tb = tables[:, idx]
-            h, logits, nc, kp, vp, ks, vs = stage_decode_paged(
-                cfg, sp, layers, tok, h_in, entry, cg, pos, kp, vp, tb,
-                k_scales=ks, v_scales=vs, interpret=interpret)
-            new = jax.tree.map(lambda full, n: full.at[idx].set(n),
-                               caches, nc)
-            return h, logits, new, kp, vp, ks, vs
-
-        self._decode = jax.jit(decode_fn,
-                               donate_argnums=() if on_cpu else (7, 8, 9, 10))
+        self._decode = jax.jit(
+            paged_decode_step(cfg, layers, interpret),
+            donate_argnums=() if on_cpu else (7, 8, 9, 10))
         # per-slot {kept_tokens: {page_id: (k, v, ks, vs)}} verify snapshots
         self._spec_snaps: Dict[int, Dict[int, dict]] = {}
 
@@ -597,11 +654,11 @@ class PagedStageEngine(_StageEngineBase):
                 idx, tok, pos, entry, h_in = self._assemble(items)
                 tables = self._put(pool.table)
             with TRACER.span("helix.engine.launch"):
-                (h, logits, self.caches, pool.k, pool.v,
+                (h, logits, ids, self.caches, pool.k, pool.v,
                  pool.k_scales, pool.v_scales) = self._decode(
                     self.sparams, self.caches, tok, h_in, entry, pos, idx,
                     pool.k, pool.v, pool.k_scales, pool.v_scales, tables)
-            return self._fetch(h, logits)
+            return self._fetch_decode(items, h, logits, ids)
 
     # -- speculative rollback --------------------------------------------
     def _spec_begin(self, it: DecodeItem) -> None:

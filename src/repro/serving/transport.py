@@ -37,8 +37,10 @@ real bytes to them:
   RemoteStageEngine the coordinator-side proxy speaking the stage-engine
                     API (``prefill_stage`` / ``prefill_chunk`` /
                     ``decode_stage`` / slot + pool bookkeeping) over a
-                    ``WorkerChannel``.  Final-stage sampling happens
-                    coordinator-side on the logits the decode reply carries.
+                    ``WorkerChannel``.  A final stage's decode reply
+                    carries each row's greedy token, and logits only for
+                    the rows that asked; sampling at temperature > 0
+                    happens coordinator-side on those logits.
 
 Nothing here imports the runtime; ``runtime.ClusterRuntime.spawn_workers``
 wires these pieces to worker processes it launches (or to externally
@@ -544,9 +546,10 @@ class SocketTransport:
 class RemoteStageEngine:
     """Stage-engine API over a WorkerChannel.  The worker owns the params,
     caches and page pool; this proxy owns only the final-stage sampling RNG
-    (greedy/temperature sampling runs coordinator-side on the logits the
-    decode reply carries, so one RNG stream drives the pipeline exactly as
-    a local engine's would).
+    (temperature sampling runs coordinator-side on the logits the decode
+    reply carries for the rows that asked, so one RNG stream drives the
+    pipeline exactly as a local engine's would; greedy rows get the
+    worker's token and no logits).
 
     ``forward_capable``: compute RPCs accept a forward spec ``fwd=(dst
     node, staging tag)``.  The worker then pushes the stage output straight
@@ -603,18 +606,19 @@ class RemoteStageEngine:
     def decode_stage(self, items: List[DecodeItem],
                      fwds: Optional[List[Optional[Tuple[str, int]]]] = None
                      ) -> List[DecodeOut]:
-        # 6-tuple wire format: ``tokens`` (a verify pass's token vector, or
-        # None) rides last so old captures stay readable; the worker
-        # resolves StagedRefs in both ``h`` and ``tokens``
-        wire = [(it.slot, it.pos, it.entry, it.token, it.h, it.tokens)
-                for it in items]
+        # 7-tuple wire format: ``tokens`` (a verify pass's token vector, or
+        # None) and then ``want_logits`` ride last so old captures stay
+        # readable; the worker resolves StagedRefs in both ``h`` and
+        # ``tokens``.  A reply is (h, logits, token) per item.
+        wire = [(it.slot, it.pos, it.entry, it.token, it.h, it.tokens,
+                 it.want_logits) for it in items]
         outs = self.channel.call("decode_stage", wire,
                                  list(fwds) if fwds else None)
         res = []
-        for i, (h, logits) in enumerate(outs):
+        for i, (h, logits, token) in enumerate(outs):
             if fwds and fwds[i] is not None:
                 h = StagedRef(fwds[i][1])
-            res.append(DecodeOut(h=h, logits=logits))
+            res.append(DecodeOut(h=h, logits=logits, token=token))
         return res
 
     def rollback(self, slot: int, tokens: int) -> None:

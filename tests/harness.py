@@ -117,6 +117,35 @@ def random_prompts(cfg, lengths: Sequence[int], *,
     return [rng.randint(0, cfg.vocab_size, size=(int(n),)) for n in lengths]
 
 
+def prefill_slots(eng, prompts, *, paged: bool, chunk: int,
+                  budget: int) -> List[int]:
+    """Seat prompt ``i`` in slot ``i`` of a whole-model stage engine (local
+    or remote), reserve ``budget`` tokens and prefill it (paged engines by
+    chunks of ``chunk``); returns each prompt's greedy first token."""
+    first = []
+    for i, p in enumerate(prompts):
+        s = eng.alloc_slot(i)
+        assert s == i and eng.ensure(s, budget)
+        if paged:
+            for off in range(0, len(p), chunk):
+                out = eng.prefill_chunk(s, p[off:off + chunk], 0, off)
+        else:
+            out = eng.prefill_stage(s, p, 0)
+        first.append(int(np.argmax(np.asarray(out, np.float64))))
+    return first
+
+
+def decode_rows(eng, prompts, toks, t: int, want_logits) -> list:
+    """Decode step ``t`` of every seated prompt (slot ``i``), feeding
+    ``toks[i]``; ``want_logits[i]`` says whether row ``i`` asks for its
+    logits."""
+    from repro.serving.stage_engine import DecodeItem
+    return eng.decode_stage([
+        DecodeItem(slot=i, pos=len(p) + t, entry=0, token=int(tok),
+                   want_logits=bool(w))
+        for i, (p, tok, w) in enumerate(zip(prompts, toks, want_logits))])
+
+
 def _as_requests(prompts, max_new_tokens) -> List[Request]:
     if isinstance(max_new_tokens, int):
         max_new_tokens = [max_new_tokens] * len(prompts)

@@ -4,6 +4,8 @@ the engine bugfix regressions (truncation, max_len, max_new_tokens=1).
 
 The smoke model + its f32 cast come from tests/harness.py //
 tests/conftest.py (``gqa_model`` is session-scoped there)."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -11,11 +13,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
+from repro.core import LayerRange
 from repro.models import decode_step, init, prefill
 from repro.models.paged import num_paged_layers
-from repro.serving import Engine, EngineConfig, PagedEngine, Request
+from repro.serving import (Engine, EngineConfig, PagedEngine, Request,
+                           make_stage_engine)
+from repro.serving.stage_engine import _greedy
+from repro.serving.trace import TRACER
 
-from harness import f32, random_prompts
+from harness import decode_rows, f32, prefill_slots, random_prompts
 
 
 def _reference_greedy(cfg, params, prompt, n_tokens, max_len=64):
@@ -237,3 +243,108 @@ def test_pool_too_small_for_one_request_raises(gqa_model):
     with pytest.raises(ValueError, match="cannot hold"):
         PagedEngine(cfg, params, EngineConfig(max_batch=2, max_len=64,
                                               prompt_len=16), num_pages=3)
+
+
+# --- greedy tokens taken on the device ----------------------------------------
+
+# small widths of the two benchmark configurations' attention, at the served
+# dtype (bf16 logits): MHA as OLMo-1B, GQA at G = 3 as SmolLM-360M
+ATTN_SHAPES = {
+    "mha": lambda: get_smoke_config("olmo_1b"),
+    "gqa3": lambda: dataclasses.replace(get_smoke_config("smollm_360m"),
+                                        d_model=96, num_heads=6,
+                                        num_kv_heads=2),
+}
+STAGE_EC = EngineConfig(max_batch=3, max_len=48, prompt_len=16)
+B = STAGE_EC.max_batch + 1          # rows of every step, scratch included
+STEPS = 3
+
+
+def _seated_engine(shape: str, paged: bool, rng_seed: int = 0):
+    """A whole-model stage engine with three prompts prefilled."""
+    cfg = ATTN_SHAPES[shape]()
+    assert cfg.num_heads // cfg.num_kv_heads == {"mha": 1, "gqa3": 3}[shape]
+    eng = make_stage_engine(cfg, init(cfg, jax.random.key(0)),
+                            LayerRange(0, cfg.num_layers), STAGE_EC,
+                            paged=paged, rng_seed=rng_seed)
+    # whole chunks, so prefill is one program
+    prompts = random_prompts(cfg, (32, 16, 16), seed=1)
+    toks = prefill_slots(eng, prompts, paged=paged,
+                         chunk=STAGE_EC.prompt_len,
+                         budget=max(map(len, prompts)) + STEPS)
+    return cfg, eng, prompts, toks
+
+
+def _last_fetch():
+    return next(s for s in reversed(TRACER.spans())
+                if s.name == "helix.engine.fetch")
+
+
+def _counter(name: str) -> int:
+    return TRACER.counters.get(name, 0)
+
+
+@pytest.mark.parametrize("shape", sorted(ATTN_SHAPES))
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_decode_token_is_host_argmax_of_logits(shape, paged):
+    """The final stage's ``DecodeOut.token`` is the host's ``np.argmax``
+    of the same step's logits, at the float64 the host sampled in."""
+    _, eng, prompts, toks = _seated_engine(shape, paged)
+    for t in range(STEPS):
+        outs = decode_rows(eng, prompts, toks, t, [True] * len(prompts))
+        for o in outs:
+            assert o.h is None and o.logits.dtype == jnp.bfloat16
+            assert o.token == int(np.argmax(np.asarray(o.logits,
+                                                       np.float64)))
+        toks = [o.token for o in outs]
+
+
+def test_greedy_ids_take_the_first_maximum():
+    logits = np.asarray([[1, 3, 3, 0], [2, 2, 2, 2], [-1, -5, -1, 7],
+                         [0, -2, 0, -2]], jnp.bfloat16)
+    ids = np.asarray(jax.jit(_greedy)(jnp.asarray(logits)))
+    assert ids.dtype == np.int32
+    assert ids.tolist() == [1, 0, 3, 0]
+    assert ids.tolist() == np.argmax(logits.astype(np.float64), -1).tolist()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_greedy_step_copies_ids_only(paged):
+    """A step of greedy rows copies ``B x 4`` bytes of ids and no logits;
+    a row that asks brings the (B, V) logits back, to it alone; and either
+    way the step is the one program the first step compiled."""
+    cfg, eng, prompts, toks = _seated_engine("gqa3", paged)
+    decode_rows(eng, prompts, toks, 0, [True] * 3)
+    compiles = _counter("compiles")
+    rows = _counter("decode.logits_rows")
+    outs = decode_rows(eng, prompts, toks, 1, [False] * 3)
+    assert _last_fetch().attrs["bytes"] == B * 4
+    assert _counter("decode.logits_rows") == rows
+    assert all(o.logits is None and o.h is None and type(o.token) is int
+               for o in outs)
+    outs = decode_rows(eng, prompts, [o.token for o in outs], 2,
+                       [False, True, False])
+    assert _last_fetch().attrs["bytes"] == B * cfg.vocab_size * 2 + B * 4
+    assert _counter("decode.logits_rows") == rows + B
+    assert [o.logits is not None for o in outs] == [False, True, False]
+    assert _counter("compiles") == compiles
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_mixed_batch_samples_as_before(paged):
+    """Rows at temperature > 0 in a batch with greedy rows draw the same
+    tokens from the same seeded RNG as when every row's logits came to the
+    host and every row went through ``sample()``."""
+    temps = (0.0, 0.8, 1.3)
+    _, new, prompts, toks = _seated_engine("mha", paged, rng_seed=7)
+    _, old, _, _ = _seated_engine("mha", paged, rng_seed=7)
+    got, want, off_argmax = [toks], [toks], 0
+    for t in range(STEPS):
+        outs = decode_rows(new, prompts, got[-1], t, [T > 0 for T in temps])
+        got.append([o.token if T <= 0 else new.sample(o.logits, T)
+                    for o, T in zip(outs, temps)])
+        off_argmax += sum(g != o.token for g, o in zip(got[-1], outs))
+        outs = decode_rows(old, prompts, want[-1], t, [True] * 3)
+        want.append([old.sample(o.logits, T) for o, T in zip(outs, temps)])
+    assert got == want
+    assert off_argmax > 0        # the sampled rows did draw from the RNG

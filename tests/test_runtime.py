@@ -13,8 +13,11 @@ import pytest
 from repro.core import (COORDINATOR, LayerRange, MILPOptions,
                         replan_after_failure)
 from repro.models.stage import stage_num_paged_layers
-from repro.serving import (ClusterRuntime, Engine, EngineConfig,
-                           InProcessTransport, PagedStageEngine, Request)
+from repro.serving import (ClusterRuntime, DecodeItem, Engine,
+                           EngineConfig, InProcessTransport,
+                           PagedStageEngine, Request)
+from repro.serving import runtime as runtime_mod
+from repro.serving.trace import TRACER
 
 from harness import (EC, assert_pools_drained, assert_serves_like_reference,
                      f32, make_disagg_plan, make_plan, pool_for_one_request,
@@ -299,6 +302,57 @@ def test_kv_estimator_sees_true_pool_occupancy(gqa_model):
     rt.run_until_done()
     for node in rt.engines:
         assert kv.usage[node] == 0
+
+
+# --- what a final stage's decode step brings back ----------------------------
+
+def _logits_rows() -> int:
+    return TRACER.counters.get("decode.logits_rows", 0)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_greedy_decode_copies_no_logits(gqa_model, reference, paged):
+    """Greedy requests take the final stage's device argmax: no decode step
+    brings full-vocabulary logits to the host, and the output still
+    matches the single engine's."""
+    cfg, params = gqa_model
+    prompts, ref = reference
+    p = make_plan(cfg, {"n0": (0, 2), "n1": (2, 4)})
+    before = _logits_rows()
+    assert_serves_like_reference(cfg, params, p, prompts, ref, paged=paged)
+    assert _logits_rows() == before
+
+
+def test_sampled_rows_fetch_logits_and_keep_their_tokens(gqa_model,
+                                                         reference,
+                                                         monkeypatch):
+    """Requests at temperature > 0 beside greedy ones: their rows bring
+    logits back, greedy requests still match the single engine, and every
+    request's tokens are those of a run in which every row's logits come
+    back."""
+    cfg, params = gqa_model
+    prompts, ref = reference
+    p = make_plan(cfg, {"n0": (0, 2), "n1": (2, 4)})
+    temps = (0.0, 0.9, 0.0, 1.2)
+
+    def serve():
+        rt = ClusterRuntime(cfg, params, p, EC, paged=True, rng_seed=5)
+        reqs = [Request(i, q, max_new_tokens=6, temperature=T)
+                for i, (q, T) in enumerate(zip(prompts, temps))]
+        for r in reqs:
+            rt.submit(r)
+        rt.run_until_done()
+        assert all(r.done for r in reqs)
+        assert_pools_drained(rt)
+        return [r.output for r in reqs]
+
+    before = _logits_rows()
+    got = serve()
+    assert _logits_rows() > before
+    assert [got[i] for i in (0, 2)] == [ref[i] for i in (0, 2)]
+    monkeypatch.setattr(runtime_mod, "DecodeItem", lambda **kw: DecodeItem(
+        **{**kw, "want_logits": True}))
+    assert serve() == got
 
 
 # --- fault injection on the in-flight window ---------------------------------

@@ -11,6 +11,7 @@ The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import functools
 import math
 import os
 import re
@@ -29,6 +30,7 @@ from repro.models.stage import (stage_decode_paged, stage_params,
                                 stage_prefill_chunk_paged)
 from repro.serving.kv_pool import (cfg_layout, full_rectangle_pages,
                                    page_bytes)
+from repro.serving.stage_engine import paged_decode_step
 
 CFG = get_config("smollm_360m")
 PAGE = 16
@@ -184,10 +186,11 @@ def _served(arch):
     return cfg, max_batch + 1, num_pages
 
 
-@pytest.mark.parametrize("arch", sorted(SERVED))
-def test_served_decode_step_has_no_pool_relayout(one_chip, arch):
-    """The served decode step over every layer, at the benchmark's slots
-    and pool, compiles, fits the chip and copies no pool-sized array."""
+@functools.lru_cache(maxsize=None)
+def _served_decode(one_chip, arch):
+    """The paged engine's decode program over every layer, at the
+    benchmark's slots and pool, lowered and compiled for the chip (the
+    engine's own step, pool donated as the engine donates it)."""
     cfg, B, num_pages = _served(arch)
     layers = LayerRange(0, cfg.num_layers)
     sp = _stage_params_shapes(one_chip, layers, cfg)
@@ -195,20 +198,38 @@ def test_served_decode_step_has_no_pool_relayout(one_chip, arch):
     row = lambda dt: _spec(one_chip, (B,), dt)
     h_in = _spec(one_chip, (B, 1, cfg.d_model), jnp.float32)
     tables = _spec(one_chip, (cfg.num_layers, B, NP), jnp.int32)
+    lowered = jax.jit(paged_decode_step(cfg, layers, interpret=False),
+                      donate_argnums=(7, 8)).lower(
+        sp, [{}] * cfg.num_layers, row(jnp.int32), h_in, row(jnp.int32),
+        row(jnp.int32), row(jnp.int32), pages, pages, None, None, tables)
+    return B, pages, lowered, lowered.compile()
 
-    def step(sp, tok, h, entry, pos, kp, vp, tb):
-        return stage_decode_paged(cfg, sp, layers, tok, h, entry,
-                                  [{}] * cfg.num_layers, pos, kp, vp, tb,
-                                  interpret=False)
 
-    compiled = jax.jit(step, donate_argnums=(5, 6)).lower(
-        sp, row(jnp.int32), h_in, row(jnp.int32), row(jnp.int32), pages,
-        pages, tables).compile()
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_served_decode_step_has_no_pool_relayout(one_chip, arch):
+    """The served decode step over every layer, at the benchmark's slots
+    and pool, compiles, fits the chip and copies no pool-sized array."""
+    _, pages, _, compiled = _served_decode(one_chip, arch)
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert _relayouts(hlo, pages.shape) == []
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_served_decode_step_returns_greedy_ids(one_chip, arch):
+    """The final stage's decode program returns each row's greedy token as
+    an ``s32[B]`` output of the same program, beside the logits: one
+    compiled module, whose entry returns the ids."""
+    B, _, lowered, compiled = _served_decode(one_chip, arch)
+    h, logits, ids = lowered.out_info[:3]
+    assert (ids.shape, ids.dtype) == ((B,), jnp.int32)
+    assert logits.shape[0] == B
+    hlo = compiled.as_text()
+    assert hlo.count("HloModule ") == 1
+    root = re.search(r"ENTRY .*?ROOT [^\n]*", hlo, re.S).group(0)
+    assert f"s32[{B}]" in root.splitlines()[-1]
 
 
 @pytest.mark.parametrize("arch", sorted(SERVED))

@@ -29,13 +29,20 @@ import time
 import numpy as np
 import pytest
 
-from repro.serving import (ClusterRuntime, FrameError, InProcessTransport,
-                           SocketTransport, StagedRef, TransportStalled,
-                           WorkerChannel, decode_payload, encode_payload,
-                           payload_bytes, recv_frame, send_frame)
+import jax
 
-from harness import (EC, assert_serves_like_reference, make_disagg_plan,
-                     make_plan)
+from repro.core import LayerRange
+from repro.launch.worker import serve_connection
+from repro.serving import (ClusterRuntime, EngineConfig, FrameError,
+                           InProcessTransport, PagedStageEngine,
+                           RemoteStageEngine, SocketTransport, StagedRef,
+                           TransportStalled, WorkerChannel, decode_payload,
+                           encode_payload, payload_bytes, recv_frame,
+                           send_frame)
+
+from harness import (EC, assert_serves_like_reference, decode_rows,
+                     make_disagg_plan, make_plan, prefill_slots,
+                     random_prompts)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -455,3 +462,48 @@ def test_socket_transport_delivers_after_ack():
     finally:
         tr.close()
         b.close()
+
+
+def test_remote_engine_returns_local_tokens(gqa_model):
+    """A stage engine behind a worker connection answers as the same
+    engine in process: greedy rows get the worker's device argmax and no
+    logits over the wire, rows at temperature > 0 get logits the proxy's
+    RNG samples exactly as the local engine's RNG does."""
+    cfg, params = gqa_model
+    ec = EngineConfig(max_batch=3, max_len=48, prompt_len=16)
+    temps = (0.0, 0.7, 0.0)
+    a, b = socket.socketpair()
+    worker = threading.Thread(target=serve_connection, args=(b,),
+                              daemon=True)
+    worker.start()
+    remote = RemoteStageEngine(WorkerChannel(a, "n0"), "n0", rng_seed=3)
+    try:
+        remote.channel.call("init", {
+            "node": "n0", "cfg": dataclasses.asdict(cfg),
+            "ec": dataclasses.asdict(ec), "layers": (0, cfg.num_layers),
+            "params": jax.tree.map(np.asarray, params), "paged": True,
+            "num_pages": None, "page_size": 16, "kv_dtype": None,
+            "rng_seed": 3})
+        local = PagedStageEngine(cfg, params, LayerRange(0, cfg.num_layers),
+                                 ec, page_size=16, rng_seed=3)
+        prompts = random_prompts(cfg, (16, 16, 32), seed=2)
+        runs = []
+        for eng in (local, remote):
+            toks = prefill_slots(eng, prompts, paged=True,
+                                 chunk=ec.prompt_len, budget=40)
+            seen = [toks]
+            for t in range(3):
+                outs = decode_rows(eng, prompts, seen[-1], t,
+                                   [T > 0 for T in temps])
+                assert [o.logits is not None for o in outs] == \
+                    [T > 0 for T in temps]
+                assert all(o.h is None for o in outs)
+                seen.append([o.token if T <= 0 else eng.sample(o.logits, T)
+                             for o, T in zip(outs, temps)])
+            runs.append(seen)
+        assert runs[0] == runs[1]
+    finally:
+        remote.close()
+        worker.join(10)
+        b.close()
+    assert not worker.is_alive()
